@@ -7,13 +7,13 @@ and sync overhead dominates):
 
 - ``digest``  — whole-manifest digesting: per-leaf ``tensor_digest`` (one
   kernel launch + one host round-trip per leaf, what the reducer did
-  before) vs ``digest_leaves`` (every leaf packed into one block grid, ONE
-  launch, ONE sync).  Reports GB/s, the measured host-sync counts, and a
+  before) vs ``digest_leaves`` (every leaf packed into block grids, ONE
+  launch and ONE sync per batch).  Reports GB/s, the measured host-sync counts, and a
   bit-identity flag — the batched digests must equal the per-leaf digests
   exactly, or fig5/fig11 decisions and CAS chunk keys would drift.
-- ``delta``   — the fused digest->compare->gather path
-  (``digest_leaves_delta``): mutate ~1%% of leaves, compare against the
-  prior manifest on device, and check the changed-index list is exact.
+- ``delta``   — the digest-then-compare path (``digest_leaves_delta``):
+  mutate ~1%% of leaves, compare against the prior manifest, and check
+  the changed-index list is exact.
 - ``chunk``   — ``array_chunk_digests_many`` vs per-payload
   ``array_chunk_digests`` on raw buffers (the serialize hot path), plus a
   prior-reuse pass over an almost-unchanged capture (the fused compare
@@ -83,14 +83,14 @@ def bench_manifest_digest(leaves, *, smoke: bool) -> tuple[dict, list[int]]:
     # measured pass (per-leaf compiles once per distinct shape, and the
     # size table cycles, so one cycle covers every shape)
     for a in leaves[:len(_LEAF_SIZES)]:
-        ops.tensor_digest(a, impl="xla")
-    ops.digest_leaves(leaves, impl="xla")
+        ops.tensor_digest(a)
+    ops.digest_leaves(leaves)
 
     t_per, per_leaf = _timed(
-        lambda: [ops.tensor_digest(a, impl="xla") for a in leaves])
+        lambda: [ops.tensor_digest(a) for a in leaves])
     syncs_per = ops.HOST_SYNCS
 
-    t_bat, batched = _timed(lambda: ops.digest_leaves(leaves, impl="xla"))
+    t_bat, batched = _timed(lambda: ops.digest_leaves(leaves))
     syncs_bat = ops.HOST_SYNCS
 
     return {
@@ -115,9 +115,9 @@ def bench_delta(leaves, prior, *, smoke: bool) -> dict:
     for j in expect:
         mutated[j] = mutated[j].copy()
         mutated[j][0] += 1.0
-    ops.digest_leaves_delta(mutated, prior, impl="xla")   # warm
+    ops.digest_leaves_delta(mutated, prior)   # warm
     t, (digests, changed) = _timed(
-        lambda: ops.digest_leaves_delta(mutated, prior, impl="xla"))
+        lambda: ops.digest_leaves_delta(mutated, prior))
     nbytes = sum(a.nbytes for a in leaves)
     return {
         "wall_seconds": round(t, 4),

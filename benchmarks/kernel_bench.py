@@ -1,5 +1,5 @@
 """Kernel micro-benches (XLA reference path on CPU; Pallas kernels are
-TPU-targeted and validated via interpret=True in tests — wall-clock numbers
+TPU-targeted and validated in interpret mode by the tests — wall-clock numbers
 here time the jnp oracle implementations the dry-run lowers)."""
 from __future__ import annotations
 
@@ -53,11 +53,11 @@ def run(smoke: bool = False) -> list[tuple[str, float, str]]:
 
     from repro.kernels.quant_blockwise.ops import quantize
     big = jax.random.normal(ks[0], (1024, 1024), jnp.float32)
-    us = _time(lambda x: quantize(x, impl="xla")[0], big, iters=it)
+    us = _time(lambda x: quantize(x)[0], big, iters=it)
     rows.append(("kernel/quant8_1M", us, f"{big.nbytes/us*1e6/1e9:.2f} GB/s"))
 
     from repro.kernels.hash_delta.ops import tensor_digest
-    us = _time(lambda x: tensor_digest(x, impl="xla"), big, iters=it)
+    us = _time(lambda x: tensor_digest(x), big, iters=it)
     rows.append(("kernel/hash_1M", us, f"{big.nbytes/us*1e6/1e9:.2f} GB/s"))
     return rows
 

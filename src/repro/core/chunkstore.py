@@ -92,17 +92,40 @@ def split_chunks(data: bytes, chunk_bytes: int = CHUNK_BYTES) -> list[bytes]:
     return [data[i:i + eff] for i in range(0, n, eff)]
 
 
-def array_chunk_digests(raw: bytes, chunk_bytes: int = CHUNK_BYTES, *,
-                        interpret: bool = False,
-                        impl: str = "xla") -> list[int]:
+def _fold_chunks(h64: np.ndarray, nlen: int, chunk_bytes: int,
+                 reuse=None, changed=None) -> list[int]:
+    """Fold a payload's per-block digests into its chunk digests (chunk
+    length mixed in, so zero-padding of the final block cannot alias a
+    shorter chunk).  With ``reuse`` — a prior chunk-digest list of the
+    same payload length — a chunk whose blocks are all unchanged keeps
+    its prior digest without a host fold."""
+    out = []
+    eff = effective_chunk_bytes(nlen, chunk_bytes)
+    for ci, start in enumerate(range(0, nlen, eff)):
+        clen = min(eff, nlen - start)
+        b0 = start // _BLOCK_BYTES
+        b1 = (start + clen + _BLOCK_BYTES - 1) // _BLOCK_BYTES
+        if reuse is not None and ci < len(reuse) and not changed[b0:b1].any():
+            out.append(reuse[ci])           # exact: lanes matched on device
+            continue
+        h = hashlib.blake2b(h64[b0:b1].tobytes(), digest_size=8)
+        h.update(clen.to_bytes(8, "little"))
+        out.append(int.from_bytes(h.digest(), "little"))
+    return out
+
+
+def _h64(h2: np.ndarray) -> np.ndarray:
+    h2 = np.asarray(h2, np.uint64)
+    return (h2[:, 1] << np.uint64(32)) | h2[:, 0]
+
+
+def array_chunk_digests(raw: bytes, chunk_bytes: int = CHUNK_BYTES) -> list[int]:
     """Per-chunk 64-bit digests of an array's raw buffer via the device
     block-digest vector (aligned 1:1 with :func:`split_chunks`).
 
     The buffer is hashed once on device (1024-byte blocks, two uint32 lanes
     each); only the (nb, 2) digest vector crosses to the host, where each
-    chunk's span of block digests is folded into one digest (chunk length
-    mixed in, so zero-padding of the final block cannot alias a shorter
-    chunk)."""
+    chunk's span of block digests is folded into one digest."""
     import jax.numpy as jnp
 
     from repro.kernels.hash_delta.ops import BLOCK, block_digests
@@ -111,31 +134,21 @@ def array_chunk_digests(raw: bytes, chunk_bytes: int = CHUNK_BYTES, *,
     n = len(raw)
     if n == 0:
         return []
-    eff = effective_chunk_bytes(n, chunk_bytes)
     buf = np.frombuffer(raw, dtype=np.uint8)
-    h2 = np.asarray(block_digests(jnp.asarray(buf), interpret=interpret,
-                                  impl=impl), dtype=np.uint64)   # (nb, 2)
-    h64 = (h2[:, 1] << np.uint64(32)) | h2[:, 0]
-    out = []
-    for start in range(0, n, eff):
-        clen = min(eff, n - start)
-        seg = h64[start // BLOCK:(start + clen + BLOCK - 1) // BLOCK]
-        h = hashlib.blake2b(seg.tobytes(), digest_size=8)
-        h.update(clen.to_bytes(8, "little"))
-        out.append(int.from_bytes(h.digest(), "little"))
-    return out
+    h64 = _h64(block_digests(jnp.asarray(buf)))
+    return _fold_chunks(h64, n, chunk_bytes)
 
 
 def array_chunk_digests_many(payloads, chunk_bytes: int = CHUNK_BYTES, *,
-                             interpret: bool = False, impl: str = "xla",
                              priors=None):
-    """Per-chunk digests for *many* raw buffers in one device launch and
-    one host sync — bit-identical to calling :func:`array_chunk_digests`
-    on each payload.
+    """Per-chunk digests for *many* raw buffers, one device launch and one
+    host sync per batch of ``hash_delta.ops.BATCH_ROWS`` blocks —
+    bit-identical to calling :func:`array_chunk_digests` on each payload.
 
     Each payload is zero-padded to the device block boundary before
-    packing, so its rows of the shared block grid equal the standalone
-    rows.  ``priors`` (optional, aligned with ``payloads``) carries
+    packing, so its rows of a batch's block grid equal the standalone
+    rows; a payload that crosses a batch boundary is split there.
+    ``priors`` (optional, aligned with ``payloads``) carries
     ``(block_h64, chunk_digests, payload_len)`` tuples from a previous
     digesting of the same logical payload: when the length still matches,
     the fused compare kernel flags unchanged blocks **on device** and any
@@ -149,84 +162,68 @@ def array_chunk_digests_many(payloads, chunk_bytes: int = CHUNK_BYTES, *,
     import jax.numpy as jnp
 
     from repro.kernels.hash_delta.ops import (
-        note_host_sync, packed_block_digests,
-        packed_block_digests_compare, staging_buffer, to_device,
+        note_host_sync, packed_block_digests, packed_block_digests_compare,
+        plan_batches, staging_buffer, to_device,
     )
 
     n = len(payloads)
-    if n == 0:
-        return [], []
     lens = [len(p) for p in payloads]
     nbs = [(ln + _BLOCK_BYTES - 1) // _BLOCK_BYTES for ln in lens]
-    total_nb = sum(nbs)
-    if total_nb == 0:                       # every payload empty
-        return [[] for _ in payloads], [np.zeros(0, np.uint64)] * n
-    # single copy pass: payloads land block-padded in one aligned buffer
-    # the device then aliases zero-copy
-    host = staging_buffer(total_nb * _BLOCK_BYTES, np.uint8)
-    off = 0
-    for p, nb in zip(payloads, nbs):
-        end = off + len(p)
-        host[off:end] = np.frombuffer(p, dtype=np.uint8)
-        off += nb * _BLOCK_BYTES
-        if off != end:
-            host[end:off] = 0
-    packed = to_device(host)
-
-    use_cmp = priors is not None and any(pr is not None for pr in priors)
-    if use_cmp:
-        prior_lanes = np.zeros((total_nb, 2), np.uint32)
-        has = np.zeros((total_nb, 1), np.uint32)
+    priors = list(priors) if priors is not None else [None] * n
+    usable = [pr is not None and pr[2] == lens[i] and len(pr[0]) == nbs[i]
+              for i, pr in enumerate(priors)]
+    h64s = [np.empty(nb, np.uint64) for nb in nbs]
+    changed = [np.ones(nb, bool) for nb in nbs]
+    for pieces in plan_batches(nbs):
+        rows = sum(r for _, _, r in pieces)
+        # single copy pass: the batch's payload slices land block-padded in
+        # one aligned buffer, which the device then aliases or copies
+        host = staging_buffer(rows * _BLOCK_BYTES, np.uint8)
         off = 0
-        for i, pr in enumerate(priors):
-            nb = nbs[i]
-            if pr is not None and pr[2] == lens[i] and len(pr[0]) == nb:
-                h64 = np.asarray(pr[0], np.uint64)
-                prior_lanes[off:off + nb, 0] = (
-                    h64 & np.uint64(0xFFFFFFFF)).astype(np.uint32)
-                prior_lanes[off:off + nb, 1] = (
-                    h64 >> np.uint64(32)).astype(np.uint32)
-                has[off:off + nb, 0] = 1
-            off += nb
-        h2, ch = jax.device_get(packed_block_digests_compare(
-            packed, jnp.asarray(prior_lanes), jnp.asarray(has),
-            interpret=interpret, impl=impl))
-        changed = ch[:, 0].astype(bool)
-    else:
-        h2 = np.asarray(packed_block_digests(
-            packed, interpret=interpret, impl=impl))
-        changed = np.ones(total_nb, bool)
-    note_host_sync()
-    h2 = h2.astype(np.uint64)
-    h64_all = (h2[:, 1] << np.uint64(32)) | h2[:, 0]
+        for i, row0, r in pieces:
+            seg = memoryview(payloads[i])[row0 * _BLOCK_BYTES:
+                                          (row0 + r) * _BLOCK_BYTES]
+            end = off + len(seg)
+            host[off:end] = np.frombuffer(seg, dtype=np.uint8)
+            off += r * _BLOCK_BYTES
+            host[end:off] = 0
+        packed = to_device(host)
+        if any(usable[i] for i, _, _ in pieces):
+            prior_lanes = np.zeros((rows, 2), np.uint32)
+            has = np.zeros((rows, 1), np.uint32)
+            off = 0
+            for i, row0, r in pieces:
+                if usable[i]:
+                    ph = np.asarray(priors[i][0], np.uint64)[row0:row0 + r]
+                    prior_lanes[off:off + r, 0] = (
+                        ph & np.uint64(0xFFFFFFFF)).astype(np.uint32)
+                    prior_lanes[off:off + r, 1] = (
+                        ph >> np.uint64(32)).astype(np.uint32)
+                    has[off:off + r, 0] = 1
+                off += r
+            h2, ch = jax.device_get(packed_block_digests_compare(
+                packed, jnp.asarray(prior_lanes), jnp.asarray(has)))
+            ch = ch[:, 0].astype(bool)
+        else:
+            h2 = np.asarray(packed_block_digests(packed))
+            ch = np.ones(rows, bool)
+        note_host_sync()
+        h64 = _h64(h2)
+        off = 0
+        for i, row0, r in pieces:
+            h64s[i][row0:row0 + r] = h64[off:off + r]
+            changed[i][row0:row0 + r] = ch[off:off + r]
+            off += r
 
-    out_chunks, out_h64 = [], []
-    off = 0
+    out_chunks = []
     for i in range(n):
-        nb, nlen = nbs[i], lens[i]
-        h64 = h64_all[off:off + nb]
-        ch_i = changed[off:off + nb]
-        off += nb
-        out_h64.append(h64)
-        if nlen == 0:
+        if lens[i] == 0:
             out_chunks.append([])
             continue
-        eff = effective_chunk_bytes(nlen, chunk_bytes)
-        pr = priors[i] if use_cmp else None
-        reuse = (pr is not None and pr[2] == nlen and len(pr[0]) == nb)
-        digs = []
-        for ci, start in enumerate(range(0, nlen, eff)):
-            clen = min(eff, nlen - start)
-            b0 = start // _BLOCK_BYTES
-            b1 = (start + clen + _BLOCK_BYTES - 1) // _BLOCK_BYTES
-            if reuse and ci < len(pr[1]) and not ch_i[b0:b1].any():
-                digs.append(pr[1][ci])      # exact: lanes matched on device
-            else:
-                h = hashlib.blake2b(h64[b0:b1].tobytes(), digest_size=8)
-                h.update(clen.to_bytes(8, "little"))
-                digs.append(int.from_bytes(h.digest(), "little"))
-        out_chunks.append(digs)
-    return out_chunks, out_h64
+        reuse = priors[i][1] if usable[i] else None
+        out_chunks.append(_fold_chunks(h64s[i], lens[i], chunk_bytes,
+                                       reuse, changed[i]))
+    return out_chunks, h64s
 
 
 # ----------------------------------------------------------------------

@@ -130,8 +130,10 @@ class _Pickler(pickle.Pickler):
         self._store = store
 
     def persistent_id(self, obj):
+        # arrays leave the stream as they are: pulling a device array to
+        # the host happens after pickling, outside its failure handler
         if _is_array(obj):
-            self._store.append(np.asarray(obj))
+            self._store.append(obj)
             return ("arr", len(self._store) - 1)
         return None
 
@@ -154,25 +156,39 @@ class _Unpickler(pickle.Unpickler):
         return self._store[idx]
 
 
-def _prepare_array(a: np.ndarray, codec: str,
-                   interpret_kernels: bool) -> tuple[dict, bytes]:
-    """Array -> (chunk-manifest meta sans digests, raw payload bytes).
+def host_array(a) -> np.ndarray:
+    """``a`` as a host numpy array, without pinning it to ``a``.
+
+    ``np.asarray`` of an accelerator-resident array caches the host copy
+    on that array for as long as the array lives, which would keep a
+    second host copy of every captured leaf.  So the pull goes through a
+    transient device copy that carries the cache and dies with the result
+    (on the CPU backend ``np.asarray`` aliases and caches nothing)."""
+    if isinstance(a, jax.Array) and any(d.platform != "cpu"
+                                        for d in a.devices()):
+        a = jax.device_put(a, a.sharding, may_alias=False)
+    return np.asarray(a)
+
+
+def _prepare_array(a, codec: str) -> tuple[dict, bytes]:
+    """Array (host or device) -> (chunk-manifest meta sans digests, raw
+    payload bytes).  A device array is quantized where it lives, then
+    pulled to the host.
 
     Digesting is deferred so the caller can batch every payload of a
     capture into one device launch (:func:`array_chunk_digests_many`)."""
     meta = {"shape": a.shape, "dtype": str(a.dtype)}
-    impl = "pallas" if interpret_kernels else "xla"
     if codec == "quant8+zstd" and a.dtype in (np.dtype("float32"),
                                               np.dtype("float64"),
                                               jnp.bfloat16.dtype):
         from repro.kernels.quant_blockwise.ops import quantize
-        q, s = quantize(jnp.asarray(a), interpret=interpret_kernels, impl=impl)
+        q, s = quantize(jnp.asarray(a))
         q = np.asarray(q)
         payload = q.tobytes()
         meta.update(quant=True, block=int(q.shape[1]),
                     scales=_compress(np.asarray(s).tobytes(), codec))
     else:
-        payload = np.ascontiguousarray(a).tobytes()
+        payload = np.ascontiguousarray(host_array(a)).tobytes()
         meta.update(quant=False)
     return meta, payload
 
@@ -196,7 +212,7 @@ def _decode_array(meta: dict, codec: str, chunks: dict[int, bytes],
         q = np.frombuffer(raw, np.int8).reshape(-1, block)
         s = np.frombuffer(_decompress(meta["scales"], codec), np.float32)
         x = dequantize(jnp.asarray(q), jnp.asarray(s), shape,
-                       jnp.dtype(dtype), impl="xla")
+                       jnp.dtype(dtype))
         return np.asarray(x)
     return np.frombuffer(raw, dtype).reshape(shape).copy()
 
@@ -277,12 +293,10 @@ class SerializedState:
 
 class StateReducer:
     def __init__(self, codec: str = "zlib", reduce_state: bool = True,
-                 interpret_kernels: bool = False,
                  chunk_bytes: int = CHUNK_BYTES):
         assert codec in CODECS, codec
         self.codec = codec
         self.reduce_state = reduce_state
-        self.interpret_kernels = interpret_kernels
         # chunk_bytes <= 0 => one chunk per payload (whole-name granularity,
         # the pre-CAS baseline; benchmarks compare against it)
         self.chunk_bytes = int(chunk_bytes)
@@ -331,27 +345,29 @@ class StateReducer:
                 store: list = []
                 buf = io.BytesIO()
                 _Pickler(buf, store).dump(obj)
-                arrays = [_prepare_array(a, codec, self.interpret_kernels)
-                          for a in store]
-                prepared.append((name, _compress(buf.getvalue(), codec),
-                                 arrays))
             except Exception as e:  # noqa: BLE001 — paper: fall back to local
                 if on_error == "skip":
                     skipped.append(name)
                     continue
                 raise SerializationFailure(f"{name}: {e}") from e
+            # device work (quantize, device->host pull) runs outside the
+            # handler: a device error is not the paper's pickling failure
+            # and must not quietly turn into a local run
+            arrays = [_prepare_array(a, codec) for a in store]
+            prepared.append((name, _compress(buf.getvalue(), codec), arrays))
 
         keys = [(name, k) for name, _, arrs in prepared
                 for k in range(len(arrs))]
         payloads = [p for _, _, arrs in prepared for _, p in arrs]
-        impl = "pallas" if self.interpret_kernels else "xla"
         digest_lists, h64s = array_chunk_digests_many(
-            payloads, self.chunk_bytes, interpret=self.interpret_kernels,
-            impl=impl, priors=[self._chunk_cache.get(k) for k in keys])
+            payloads, self.chunk_bytes,
+            priors=[self._chunk_cache.get(k) for k in keys])
         if len(self._chunk_cache) > 4096:   # bounded: priors are a cache
             self._chunk_cache.clear()
-        for key, p, digs, h64 in zip(keys, payloads, digest_lists, h64s):
-            self._chunk_cache[key] = (h64, digs, len(p))
+        for key, n, digs, h64 in zip(keys, map(len, payloads), digest_lists,
+                                     h64s):
+            self._chunk_cache[key] = (h64, digs, n)
+        del payloads       # each payload is freed once its chunks exist
 
         pos = 0
         for name, pickle_bytes, arrays in prepared:
@@ -363,7 +379,9 @@ class StateReducer:
             added: list[int] = []
             try:
                 metas = []
-                for (meta, payload), digests_a in zip(arrays, digs_here):
+                for k, digests_a in enumerate(digs_here):
+                    meta, payload = arrays[k]
+                    arrays[k] = None     # the chunks below now hold it
                     clens = []
                     for d, chunk in zip(digests_a,
                                         split_chunks(payload,
@@ -430,7 +448,7 @@ class StateReducer:
         wide = a.dtype.itemsize > 4 or a.dtype.kind == "c"
         if isinstance(a, jax.Array) and not wide:
             return a                      # device leaf: hash on device
-        a = np.asarray(a)
+        a = host_array(a)
         if a.dtype.kind == "O":
             raise TypeError("object arrays have no stable bit pattern")
         if not wide and a.dtype.kind in "biuf":
@@ -446,9 +464,7 @@ class StateReducer:
     def _array_digest(self, a) -> int:
         """Per-leaf device digest (wide host dtypes re-lane'd first)."""
         from repro.kernels.hash_delta.ops import tensor_digest
-        impl = "pallas" if self.interpret_kernels else "xla"
-        return tensor_digest(jnp.asarray(self._hashable_leaf(a)),
-                             interpret=self.interpret_kernels, impl=impl)
+        return tensor_digest(self._hashable_leaf(a))
 
     def _host_digest(self, obj) -> int:
         """Pickle-stream blake2b for objects that are not pure array trees."""
@@ -460,7 +476,7 @@ class StateReducer:
             return -1  # unhashable => always migrate (paper §II-D)
         h = hashlib.blake2b(buf.getvalue(), digest_size=8)
         for a in store:
-            h.update(np.ascontiguousarray(a).tobytes())
+            h.update(np.ascontiguousarray(host_array(a)).tobytes())
             h.update(str(a.shape).encode())
         return int.from_bytes(h.digest(), "little")
 
@@ -522,9 +538,7 @@ class StateReducer:
         slots, leaves, host = self._split_for_batch(objs)
         out = {n: self._host_digest(o) for n, o in host.items()}
         if slots:
-            impl = "pallas" if self.interpret_kernels else "xla"
-            ds = digest_leaves(leaves, interpret=self.interpret_kernels,
-                               impl=impl)
+            ds = digest_leaves(leaves)
             out.update(self._fold_slots(slots, ds))
         return out
 
@@ -562,9 +576,7 @@ class StateReducer:
                 else:
                     prior.extend([None] * k)
                 i += k
-            impl = "pallas" if self.interpret_kernels else "xla"
-            ds, changed = digest_leaves_delta(
-                leaves, prior, interpret=self.interpret_kernels, impl=impl)
+            ds, changed = digest_leaves_delta(leaves, prior)
             folded = self._fold_slots(slots, ds)
             here.update(folded)
             send.update(leaf_name[j] for j in changed if j in leaf_name)

@@ -1101,7 +1101,11 @@ class SubprocessEnv:
     engine and runtime expect (``execute``, ``state``, ``chunk_store``,
     lifecycle attrs) while every state movement rides SocketTransport
     frames.  ``state`` here is an empty mirror — the truth lives remotely,
-    which is exactly what forces the protocol to be honest."""
+    which is exactly what forces the protocol to be honest.
+
+    The child stands in for another host, so it runs JAX on its own CPU
+    (``JAX_PLATFORMS=cpu``): an accelerator belongs to one process at a
+    time, and the parent may already hold it."""
 
     kind = "compute"
 
@@ -1127,6 +1131,7 @@ class SubprocessEnv:
             os.path.abspath(__file__))))
         env = dict(os.environ)
         env["PYTHONPATH"] = pkg_root + os.pathsep + env.get("PYTHONPATH", "")
+        env["JAX_PLATFORMS"] = "cpu"
         self.proc = subprocess.Popen(
             [python or sys.executable, "-m", "repro.core.remote_worker",
              "--connect", f"127.0.0.1:{port}", "--codec", codec],

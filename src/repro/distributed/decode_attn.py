@@ -65,8 +65,7 @@ def sp_decode_attention(ctx, q, k_cache, v_cache, new_k, new_v, pos):
         out = (o_g / jnp.maximum(l_g, 1e-30)[..., None]).astype(q.dtype)
         return out.reshape(Bl, 1, H, hd), k, v
 
-    from repro.distributed.sharding import shard_map
-    fn = shard_map(
+    fn = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(rep, cache_spec, cache_spec, rep, rep, PS(batch)),
         out_specs=(rep, cache_spec, cache_spec),

@@ -10,19 +10,6 @@ from repro.distributed.context import DistContext
 from repro.optim.optimizer import OptState
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None):
-    """``jax.shard_map`` across jax versions: older releases only ship
-    ``jax.experimental.shard_map`` and spell ``check_vma`` as ``check_rep``."""
-    if hasattr(jax, "shard_map"):
-        kw = {} if check_vma is None else {"check_vma": check_vma}
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    kw = {} if check_vma is None else {"check_rep": check_vma}
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kw)
-
-
 def fsdp_sharding(ctx: DistContext, axes: tuple, shape: tuple) -> NamedSharding:
     """Fully shard a parameter over ALL mesh axes (zero-3/FSDP): the first
     dim divisible by the full mesh size gets the flattened axes; fallbacks

@@ -16,7 +16,7 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
                     impl: str = "pallas"):
     """q (B,H,S,hd); k/v (B,KV,S,hd) -> (B,H,S,hd).
 
-    impl="pallas": the TPU kernel (interpret=True to validate on CPU).
+    impl="pallas": the TPU kernel (tests validate it on CPU in interpret mode).
     impl="xla": the pure-jnp oracle.
     """
     if impl == "xla":

@@ -8,21 +8,28 @@ combined on the host as ``(hi << 32) | lo`` — Pallas/XLA arithmetic stays
 uint32 throughout, so no x64 mode is required, yet the digest space is a
 true 2^64 (the pre-CAS version returned a single uint32).
 
-**Batched manifest digesting.**  ``digest_leaves`` packs every leaf of a
-manifest — ragged sizes, mixed numpy/jax residency — into one block grid
-and digests the whole namespace in a *single* kernel launch with a single
-device->host sync, instead of one launch + one ``np.asarray`` round-trip
-per leaf.  ``digest_leaves_delta`` fuses the compare against the prior
-manifest's digest vector on device and gathers only the changed-leaf index
-list to the host.  Both are bit-identical to the per-leaf path: each leaf
-is padded to its own block boundary (so per-block digests are unchanged)
-and the per-leaf fold is an unsigned 32-bit weighted sum, which is exactly
-associative/commutative mod 2^32 — ``segment_sum`` over the packed grid
-therefore reproduces ``tensor_digest`` bit for bit.
+**Which implementation runs.**  :func:`kernel_mode` is the one place that
+chooses: the compiled Pallas kernel where the data lives on a TPU, the XLA
+formulation (``ref.block_hash_ref``) elsewhere.  Both give the same bits,
+so a digest never depends on where it was computed.  ``interpret``
+runs the Pallas kernel in interpret mode; only tests set it.
+
+**Batched manifest digesting.**  ``digest_leaves`` packs the leaves of a
+manifest — ragged sizes, mixed numpy/jax residency — into block grids of at
+most :data:`BATCH_ROWS` rows and digests each grid in one kernel launch
+with one device->host sync, instead of one launch + one ``np.asarray``
+round-trip per leaf.  A capture below one batch therefore costs exactly one
+launch and one sync, and a larger one holds at most one batch beyond its
+resident state on the device.  The result is bit-identical to the per-leaf
+path: each leaf is padded to its own block boundary (so per-block digests
+are unchanged) and the per-leaf fold is an unsigned 32-bit weighted sum,
+which is exactly associative/commutative mod 2^32 — ``segment_sum`` over a
+grid, and the host's sum of a split leaf's partial lanes across grids,
+therefore reproduce ``tensor_digest`` bit for bit.
 
 ``HOST_SYNCS`` counts device->host materializations issued by this module
-(one per ``tensor_digest``, one per batched call) so benchmarks and tests
-can assert the O(leaves) -> O(1) reduction.
+(one per ``tensor_digest``, one per batch) so benchmarks and tests can
+assert the O(leaves) -> O(batches) reduction.
 """
 from __future__ import annotations
 
@@ -33,8 +40,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.hash_delta.kernel import (
+    block_hash_compare_kernel, block_hash_kernel,
+)
+from repro.kernels.hash_delta.ref import block_hash_compare_ref, block_hash_ref
+
 BLOCK = 1024
 LANES = 2
+BATCH_ROWS = 1 << 16            # block rows per launch: 256 MiB of uint32
 _FOLD = np.uint32(2246822519)   # per-block position weight (golden-prime)
 
 HOST_SYNCS = 0                  # device->host syncs since reset_host_syncs()
@@ -65,6 +78,35 @@ _W = np.stack([
 ])
 
 
+def kernel_mode(x, interpret: bool = False) -> str:
+    """Implementation for hashing ``x``: ``"pallas"`` when it lives on a
+    TPU, ``"xla"`` otherwise, ``"interpret"`` when a test asks for the
+    Pallas kernel in interpret mode.  Host data counts as living on the
+    default device, where :func:`to_device` and ``jit`` place it."""
+    if interpret:
+        return "interpret"
+    if isinstance(x, jax.Array):
+        dev = next(iter(x.devices()))
+    else:
+        dev = jax.devices()[0]
+    return "pallas" if dev.platform == "tpu" else "xla"
+
+
+def _lanes(x2d, mode: str):
+    w = jnp.asarray(_W)
+    if mode == "xla" or x2d.shape[0] == 0:   # a kernel needs a non-empty grid
+        return block_hash_ref(x2d, w)
+    return block_hash_kernel(x2d, w, interpret=mode == "interpret")
+
+
+def _lanes_compare(x2d, prior, has_prior, mode: str):
+    w = jnp.asarray(_W)
+    if mode == "xla" or x2d.shape[0] == 0:
+        return block_hash_compare_ref(x2d, w, prior, has_prior)
+    return block_hash_compare_kernel(x2d, w, prior, has_prior,
+                                     interpret=mode == "interpret")
+
+
 def _as_u32_blocks(x):
     if jnp.issubdtype(x.dtype, jnp.floating):
         raw = jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.uint32)
@@ -77,86 +119,65 @@ def _as_u32_blocks(x):
     return jnp.pad(flat, (0, pad)).reshape(-1, BLOCK)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "impl"))
-def block_digests(x, *, interpret: bool = False, impl: str = "pallas"):
+@functools.partial(jax.jit, static_argnames=("mode",))
+def _block_lanes(x, *, mode: str):
+    return _lanes(_as_u32_blocks(x), mode)
+
+
+def block_digests(x):
     """Any tensor -> (nb, 2) uint32 per-block digest lanes (on device).
 
     One row per 1024-element block; the two lanes together are the block's
     64-bit identity.  Only this (nb, 2) vector crosses to the host — never
     the tensor itself."""
-    x2d = _as_u32_blocks(x)
-    if impl == "xla":
-        from repro.kernels.hash_delta.ref import block_hash_ref
-        return block_hash_ref(x2d, jnp.asarray(_W))
-    from repro.kernels.hash_delta.kernel import block_hash_kernel
-    return block_hash_kernel(x2d, jnp.asarray(_W), interpret=interpret)
+    return _block_lanes(x, mode=kernel_mode(x))
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "impl"))
-def _digest_lanes(x, *, interpret: bool = False, impl: str = "pallas"):
+@functools.partial(jax.jit, static_argnames=("mode",))
+def _digest_lanes(x, *, mode: str):
     """Weighted fold of the per-block vector -> (2,) uint32 (host-free)."""
-    h2 = block_digests(x, interpret=interpret, impl=impl)
+    h2 = _block_lanes(x, mode=mode)
     idx = (jnp.arange(h2.shape[0], dtype=jnp.uint32)
            * _FOLD + jnp.uint32(1))
     return jnp.sum(h2 * idx[:, None], axis=0, dtype=jnp.uint32)
 
 
-def tensor_digest(x, *, interpret: bool = False, impl: str = "pallas") -> int:
+def tensor_digest(x, *, interpret: bool = False) -> int:
     """Any tensor -> one 64-bit int digest (content hash for delta migration)."""
-    lo, hi = np.asarray(_digest_lanes(x, interpret=interpret, impl=impl))
+    lo, hi = np.asarray(_digest_lanes(x, mode=kernel_mode(x, interpret)))
     _note_sync()
     return (int(hi) << 32) | int(lo)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "impl"))
-def block_digests_compare(x, prior, has_prior, *, interpret: bool = False,
-                          impl: str = "pallas"):
-    """Fused per-block digest + compare against a prior digest vector.
-
-    ``prior`` is (nb, 2) uint32 (the previous manifest's block lanes for
-    this tensor) and ``has_prior`` is (nb, 1) uint32 validity flags.
-    Returns ``(h, changed)``: ``h`` bit-identical to :func:`block_digests`,
-    ``changed`` a (nb, 1) uint32 flag per block — the comparison happens in
-    the same launch as the hash, so only flags ever cross to the host."""
-    x2d = _as_u32_blocks(x)
-    if impl == "xla":
-        from repro.kernels.hash_delta.ref import block_hash_compare_ref
-        return block_hash_compare_ref(x2d, jnp.asarray(_W), prior, has_prior)
-    from repro.kernels.hash_delta.kernel import block_hash_compare_kernel
-    return block_hash_compare_kernel(x2d, jnp.asarray(_W), prior, has_prior,
-                                     interpret=interpret)
+@functools.partial(jax.jit, static_argnames=("mode",))
+def _packed_lanes(u8, *, mode: str):
+    return _lanes(u8.astype(jnp.uint32).reshape(-1, BLOCK), mode)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "impl"))
-def packed_block_digests(u8, *, interpret: bool = False,
-                         impl: str = "pallas"):
+@functools.partial(jax.jit, static_argnames=("mode",))
+def _packed_lanes_compare(u8, prior, has_prior, *, mode: str):
+    return _lanes_compare(u8.astype(jnp.uint32).reshape(-1, BLOCK), prior,
+                          has_prior, mode)
+
+
+def packed_block_digests(u8):
     """Pre-packed byte buffer (size % BLOCK == 0) -> (nb, 2) lanes.
 
     The caller has already zero-padded each constituent payload to its own
     block boundary, so every row equals the row :func:`block_digests` would
     produce for that payload standalone."""
-    return _lanes_impl(u8.astype(jnp.uint32).reshape(-1, BLOCK),
-                       interpret, impl)
+    return _packed_lanes(u8, mode=kernel_mode(u8))
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "impl"))
-def packed_block_digests_compare(u8, prior, has_prior, *,
-                                 interpret: bool = False,
-                                 impl: str = "pallas"):
+def packed_block_digests_compare(u8, prior, has_prior):
     """Fused variant of :func:`packed_block_digests`: digest + compare
     against a prior (nb, 2) lane vector in one launch; returns
     ``(h, changed)`` with ``changed`` (nb, 1) uint32."""
-    x2d = u8.astype(jnp.uint32).reshape(-1, BLOCK)
-    if impl == "xla":
-        from repro.kernels.hash_delta.ref import block_hash_compare_ref
-        return block_hash_compare_ref(x2d, jnp.asarray(_W), prior, has_prior)
-    from repro.kernels.hash_delta.kernel import block_hash_compare_kernel
-    return block_hash_compare_kernel(x2d, jnp.asarray(_W), prior, has_prior,
-                                     interpret=interpret)
+    return _packed_lanes_compare(u8, prior, has_prior, mode=kernel_mode(u8))
 
 
 # ----------------------------------------------------------------------
-# batched manifest digesting: many ragged leaves, one launch, one sync
+# batched manifest digesting: many ragged leaves, few launches, few syncs
 # ----------------------------------------------------------------------
 
 _ALIGN = 64   # XLA:CPU buffer alignment — required for zero-copy import
@@ -167,7 +188,7 @@ def aligned_empty(n: int, dtype=np.uint32) -> np.ndarray:
 
     numpy only guarantees 16-byte alignment, which forces jax's dlpack
     import to copy; carving the view out of an oversized uint8 buffer
-    makes :func:`to_device` a true zero-copy alias."""
+    makes :func:`to_device` a true zero-copy alias on the CPU."""
     itemsize = np.dtype(dtype).itemsize
     raw = np.empty(n * itemsize + _ALIGN, np.uint8)
     off = (-raw.ctypes.data) % _ALIGN
@@ -175,35 +196,33 @@ def aligned_empty(n: int, dtype=np.uint32) -> np.ndarray:
 
 
 def to_device(a: np.ndarray):
-    """Host array -> device array, zero-copy when 64-byte aligned.
+    """Host array -> array on the default device (``jax.devices()[0]``).
 
-    The device array aliases the host buffer, so callers must not mutate
-    ``a`` afterwards.  Falls back to a copying transfer when the buffer
-    cannot be shared (misaligned, or no dlpack support)."""
-    try:
-        return jnp.from_dlpack(a)
-    except Exception:
-        return jnp.asarray(a)
+    Where that device is the CPU, the result aliases ``a`` (zero-copy when
+    64-byte aligned), so callers must not mutate ``a`` while it is in use.
+    Elsewhere the buffer is copied to the device."""
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
+        try:
+            return jnp.from_dlpack(a)
+        except (TypeError, ValueError, RuntimeError):
+            pass     # not shareable: fall through to a copying transfer
+    return jax.device_put(a, dev)
 
 
-_STAGING_CAP = 3 << 29          # max bytes kept alive per dtype: covers a
-                                # GiB-scale manifest plus block padding
 _STAGING = threading.local()
 
 
 def staging_buffer(n: int, dtype=np.uint32) -> np.ndarray:
-    """Aligned staging buffer, reused across calls (per thread, capped).
+    """Aligned staging buffer, reused across calls (per thread).
 
     First-touch page faults dominate the cost of a fresh ``np.empty`` —
     roughly 7x the price of refilling warm pages — so batch digesting
     stages through a recycled buffer.  Reuse is only safe because every
-    batched entry point syncs (``device_get``) before returning: once a
-    call is over, no live device array aliases the buffer.  Requests
-    beyond the cap fall back to a fresh allocation rather than pinning
-    manifest-sized memory forever."""
-    nbytes = n * np.dtype(dtype).itemsize
-    if nbytes > _STAGING_CAP:
-        return aligned_empty(n, dtype)
+    batch syncs (``device_get``) before the next one fills the buffer:
+    once a batch is over, no live device array aliases it.  Requests are
+    at most one batch (``BATCH_ROWS * BLOCK`` elements), so that bounds
+    what the pool keeps alive per dtype."""
     pool = getattr(_STAGING, "pool", None)
     if pool is None:
         pool = _STAGING.pool = {}
@@ -211,9 +230,34 @@ def staging_buffer(n: int, dtype=np.uint32) -> np.ndarray:
     buf = pool.get(key)
     if buf is None or buf.size < n:
         grown = 0 if buf is None else 2 * buf.size
-        cap = _STAGING_CAP // np.dtype(dtype).itemsize
-        buf = pool[key] = aligned_empty(min(cap, max(n, grown)), dtype)
+        buf = pool[key] = aligned_empty(
+            max(n, min(grown, BATCH_ROWS * BLOCK)), dtype)
     return buf[:n]
+
+
+def plan_batches(nbs) -> list[list[tuple[int, int, int]]]:
+    """Cut ragged items of ``nbs[i]`` block rows into batches of at most
+    :data:`BATCH_ROWS` rows, in order.
+
+    Each batch is a list of pieces ``(item, first_row, rows)``; an item
+    that crosses a batch boundary is split there, so every batch but the
+    last is exactly full.  Empty items get no piece."""
+    batches: list[list[tuple[int, int, int]]] = []
+    cur: list[tuple[int, int, int]] = []
+    used = 0
+    for i, nb in enumerate(nbs):
+        row = 0
+        while row < nb:
+            take = min(nb - row, BATCH_ROWS - used)
+            cur.append((i, row, take))
+            row += take
+            used += take
+            if used == BATCH_ROWS:
+                batches.append(cur)
+                cur, used = [], 0
+    if cur:
+        batches.append(cur)
+    return batches
 
 
 def _np_u32_flat(a: np.ndarray) -> np.ndarray:
@@ -235,113 +279,76 @@ def _np_u32_flat(a: np.ndarray) -> np.ndarray:
 _prep_blocks = jax.jit(_as_u32_blocks)
 
 
-def _pack_leaves(leaves):
-    """Ragged leaves -> one (NB, BLOCK) uint32 grid + blocks-per-leaf.
+def _is_host(a) -> bool:
+    return isinstance(a, (np.ndarray, np.generic))
 
-    Each leaf is padded to its own block boundary before packing, so every
-    row of the grid equals the row the per-leaf path would have hashed.
-    numpy-resident leaves are gathered host-side (one copy pass, slice-
-    assigned into a recycled aligned staging buffer) and shipped
-    zero-copy; jax-resident leaves are prepped on device and never visit
-    the host.  All host runs carve disjoint slices of ONE staging buffer —
-    a per-run buffer would let a later fill clobber an earlier run's
-    still-pending device alias."""
-    order = []       # ("host", flat_views, run_nb) | ("dev", blocks, None)
-    nbs = []
-    host_nb = 0
-    run, run_nb = [], 0
+
+def _pack_batch(leaves, pieces, split: dict):
+    """One batch of pieces -> its (rows, BLOCK) uint32 grid on the device.
+
+    Every row equals the row the per-leaf path would have hashed: each
+    leaf is padded to its own block boundary.  Host pieces are gathered
+    into one aligned staging buffer (one copy pass) and placed on the
+    default device with :func:`to_device`; device pieces are prepped where
+    they live and never visit the host.  A device leaf split across
+    batches is widened once and kept in ``split`` while its batches run
+    (the slice-before-widen alternative materializes the flattened leaf
+    on a TPU anyway)."""
+    host_rows = sum(rows for i, _, rows in pieces if _is_host(leaves[i]))
+    dst = staging_buffer(host_rows * BLOCK) if host_rows else None
+    parts, off, run_lo = [], 0, 0
 
     def _close_run():
-        nonlocal run, run_nb, host_nb
-        if run_nb:
-            order.append(("host", run, run_nb))
-            host_nb += run_nb
-        run, run_nb = [], 0
+        if off > run_lo:
+            # run offsets are BLOCK-row multiples, so slices stay aligned
+            parts.append(to_device(dst[run_lo:off].reshape(-1, BLOCK)))
 
-    for a in leaves:
-        if isinstance(a, (np.ndarray, np.generic)):
-            flat = _np_u32_flat(np.asarray(a))
-            nb = -(-flat.size // BLOCK)
-            run.append(flat)
-            run_nb += nb
-            nbs.append(nb)
-        else:
-            _close_run()
-            b = _prep_blocks(a)
-            if b.shape[0]:
-                order.append(("dev", b, None))
-            nbs.append(int(b.shape[0]))
-    _close_run()
-
-    if host_nb:
-        dst = staging_buffer(host_nb * BLOCK)
-    parts, off = [], 0
-    for kind, payload, _nb in order:
-        if kind == "dev":
-            parts.append(payload)
-            continue
-        lo = off
-        for flat in payload:
+    for i, row0, rows in pieces:
+        a = leaves[i]
+        if _is_host(a):
+            flat = _np_u32_flat(
+                np.ravel(np.asarray(a))[row0 * BLOCK:(row0 + rows) * BLOCK])
             end = off + flat.size
             dst[off:end] = flat
-            off += -(-flat.size // BLOCK) * BLOCK
-            if off != end:
-                dst[end:off] = 0
-        # run offsets are BLOCK-row multiples, so slices stay 64B-aligned
-        parts.append(to_device(dst[lo:off].reshape(-1, BLOCK)))
-    if not parts:
-        return jnp.zeros((0, BLOCK), jnp.uint32), nbs
-    x2d = parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
-    return x2d, nbs
+            off += rows * BLOCK
+            dst[end:off] = 0
+            continue
+        _close_run()
+        run_lo = off
+        if row0 == 0 and rows == -(-a.size // BLOCK):
+            parts.append(_prep_blocks(a))
+            continue
+        if i not in split:
+            split.clear()              # the previous split leaf is done
+            split[i] = _prep_blocks(a)
+        parts.append(jax.lax.dynamic_slice_in_dim(split[i], row0, rows))
+    _close_run()
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
 
 
-def _fold_weights(nbs):
-    """Per-block fold weight + leaf segment id (host-side, shapes static)."""
-    nbs_a = np.asarray(nbs, np.int64)
-    total = int(nbs_a.sum())
-    seg = np.repeat(np.arange(len(nbs_a), dtype=np.int32), nbs_a)
-    starts = np.repeat(np.cumsum(nbs_a) - nbs_a, nbs_a)
-    local = (np.arange(total, dtype=np.int64) - starts).astype(np.uint32)
-    idx = local * _FOLD + np.uint32(1)
-    return idx, seg
+def _fold_weights(pieces):
+    """Per-row fold weight + piece segment id (host-side, shapes static).
+
+    A row's weight depends on its position within its *leaf*, so the
+    pieces of a split leaf carry their leaf-local row offsets."""
+    rows = np.asarray([r for _, _, r in pieces], np.int64)
+    row0 = np.asarray([r0 for _, r0, _ in pieces], np.int64)
+    seg = np.repeat(np.arange(len(pieces), dtype=np.int32), rows)
+    starts = np.repeat(np.cumsum(rows) - rows - row0, rows)
+    local = (np.arange(int(rows.sum()), dtype=np.int64) - starts)
+    return local.astype(np.uint32) * _FOLD + np.uint32(1), seg
 
 
-def _lanes_impl(x2d, interpret: bool, impl: str):
-    if impl == "xla":
-        from repro.kernels.hash_delta.ref import block_hash_ref
-        return block_hash_ref(x2d, jnp.asarray(_W))
-    from repro.kernels.hash_delta.kernel import block_hash_kernel
-    return block_hash_kernel(x2d, jnp.asarray(_W), interpret=interpret)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("num_leaves", "interpret", "impl"))
-def _batched_lanes(x2d, idx, seg, *, num_leaves: int,
-                   interpret: bool = False, impl: str = "pallas"):
-    """One launch over the packed grid -> (num_leaves, 2) digest lanes.
+@functools.partial(jax.jit, static_argnames=("num_segments", "mode"))
+def _batched_lanes(x2d, idx, seg, *, num_segments: int, mode: str):
+    """One launch over a packed grid -> (num_segments, 2) lane sums.
 
     The per-leaf fold is a weighted uint32 sum; ``segment_sum`` reorders
     additions but unsigned add is associative/commutative mod 2^32, so the
     result is bit-identical to the per-leaf ``jnp.sum``."""
-    h2 = _lanes_impl(x2d, interpret, impl)
+    h2 = _lanes(x2d, mode)
     return jax.ops.segment_sum(h2 * idx[:, None], seg,
-                               num_segments=num_leaves)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("num_leaves", "interpret", "impl"))
-def _batched_delta(x2d, idx, seg, prior, has_prior, *, num_leaves: int,
-                   interpret: bool = False, impl: str = "pallas"):
-    """Fused digest -> compare -> gather, entirely on device.
-
-    Returns (lanes, changed_idx) where changed_idx is the (num_leaves,)
-    gathered index vector of changed leaves, padded with ``num_leaves``."""
-    lanes = _batched_lanes(x2d, idx, seg, num_leaves=num_leaves,
-                           interpret=interpret, impl=impl)
-    changed = (~has_prior) | jnp.any(lanes != prior, axis=1)
-    (ch_idx,) = jnp.nonzero(changed, size=num_leaves,
-                            fill_value=num_leaves)
-    return lanes, ch_idx
+                               num_segments=num_segments)
 
 
 def _fold_digests(lanes: np.ndarray) -> list[int]:
@@ -349,59 +356,41 @@ def _fold_digests(lanes: np.ndarray) -> list[int]:
     return ((lanes[:, 1] << np.uint64(32)) | lanes[:, 0]).tolist()
 
 
-def digest_leaves(leaves, *, interpret: bool = False,
-                  impl: str = "pallas") -> list[int]:
-    """Digest a whole manifest of leaves in one launch + one host sync.
+def digest_leaves(leaves, *, interpret: bool = False) -> list[int]:
+    """Digest a whole manifest of leaves, one launch + one host sync per
+    batch of :data:`BATCH_ROWS` block rows.
 
     Returns one 64-bit digest per leaf, in order, bit-identical to calling
-    :func:`tensor_digest` on each leaf individually."""
+    :func:`tensor_digest` on each leaf individually (a leaf with no blocks
+    digests to 0)."""
     leaves = list(leaves)
-    n = len(leaves)
-    if n == 0:
-        return []
-    x2d, nbs = _pack_leaves(leaves)
-    if x2d.shape[0] == 0:       # all leaves empty: digest of no blocks is 0
-        return [0] * n
-    idx, seg = _fold_weights(nbs)
-    lanes = np.asarray(_batched_lanes(
-        x2d, jnp.asarray(idx), jnp.asarray(seg), num_leaves=n,
-        interpret=interpret, impl=impl))
-    _note_sync()
-    return _fold_digests(lanes)
+    totals = np.zeros((len(leaves), LANES), np.uint32)
+    split: dict = {}
+    for pieces in plan_batches([-(-int(np.size(a)) // BLOCK)
+                                for a in leaves]):
+        x2d = _pack_batch(leaves, pieces, split)
+        idx, seg = _fold_weights(pieces)
+        # bucket the segment count so batches share compilations
+        nseg = max(8, 1 << (len(pieces) - 1).bit_length())
+        lanes = np.asarray(_batched_lanes(
+            x2d, jnp.asarray(idx), jnp.asarray(seg), num_segments=nseg,
+            mode=kernel_mode(x2d, interpret)))
+        _note_sync()
+        # a leaf has at most one piece per batch; uint32 adds wrap mod 2^32
+        totals[[i for i, _, _ in pieces]] += lanes[:len(pieces)]
+    return _fold_digests(totals)
 
 
-def digest_leaves_delta(leaves, prior_digests, *, interpret: bool = False,
-                        impl: str = "pallas"):
-    """Digest + delta for a whole manifest: one launch, one host sync.
+def digest_leaves_delta(leaves, prior_digests):
+    """Digest + delta for a whole manifest (syncs as :func:`digest_leaves`).
 
     ``prior_digests`` aligns with ``leaves``: the prior 64-bit digest of
     each leaf, or ``None`` when there is no prior (leaf counts as changed).
     Returns ``(digests, changed)`` — per-leaf 64-bit digests (bit-identical
     to :func:`tensor_digest`) and the sorted index list of leaves whose
-    digest differs from its prior.  The compare and the changed-index
-    gather both run on device; only (n, 2) lanes + (n,) indices cross."""
-    leaves = list(leaves)
-    n = len(leaves)
-    if n == 0:
-        return [], []
-    prior = np.zeros((n, LANES), np.uint32)
-    has_prior = np.zeros(n, bool)
-    for j, d in enumerate(prior_digests):
-        if d is not None:
-            prior[j, 0] = np.uint32(d & 0xFFFFFFFF)
-            prior[j, 1] = np.uint32((d >> 32) & 0xFFFFFFFF)
-            has_prior[j] = True
-    x2d, nbs = _pack_leaves(leaves)
-    if x2d.shape[0] == 0:
-        digests = [0] * n
-        changed = [j for j in range(n)
-                   if not has_prior[j] or prior_digests[j] != 0]
-        return digests, changed
-    idx, seg = _fold_weights(nbs)
-    lanes, ch_idx = jax.device_get(_batched_delta(
-        x2d, jnp.asarray(idx), jnp.asarray(seg), jnp.asarray(prior),
-        jnp.asarray(has_prior), num_leaves=n, interpret=interpret,
-        impl=impl))
-    _note_sync()
-    changed = [int(j) for j in ch_idx if j < n]
-    return _fold_digests(lanes), changed
+    digest differs from its prior.  Only (n, 2) lanes per batch cross to
+    the host, and the compare of n digests runs there."""
+    digests = digest_leaves(leaves)
+    changed = [j for j, (d, p) in enumerate(zip(digests, prior_digests))
+               if p is None or p != d]
+    return digests, changed

@@ -1,27 +1,21 @@
-import os
-os.environ["XLA_FLAGS"] = (os.environ.get("REPRO_XLA_EXTRA", "") +
-                           " --xla_force_host_platform_device_count=" +
-                           os.environ.get("REPRO_DRYRUN_DEVICES", "512")).strip()
-# ^ MUST run before any jax import: jax locks the device count on first init.
-
 """Multi-pod dry-run: lower + compile every (arch x shape) cell on the
-production mesh and record memory/cost/collective analysis (DESIGN.md,
-EXPERIMENTS.md §Dry-run).
+production mesh and record memory/cost/collective analysis.
 
     PYTHONPATH=src python -m repro.launch.dryrun --arch yi-6b --shape train_4k
     PYTHONPATH=src python -m repro.launch.dryrun --all --mesh both
+
+The CPU backend stands in for the mesh: ``main`` asks XLA for
+``REPRO_DRYRUN_DEVICES`` (default 512) host devices before JAX starts.
 """
 import argparse
 import json
+import os
 import re
 import time
 import traceback
 
 import jax
-
-jax.config.update("jax_compilation_cache_dir", "/root/repo/.jax_cache")
-jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+from jax.sharding import AxisType
 
 from repro.configs import SHAPES, TrainConfig, get_config, shape_applicable
 from repro.configs.registry import ASSIGNED_ARCHS
@@ -68,7 +62,8 @@ def _shape_bytes(type_str: str) -> int:
 
 def parse_collectives(hlo_text: str) -> dict:
     """Sum result-shape bytes per collective op; estimate wire bytes with a
-    ring model (documented in EXPERIMENTS.md §Roofline)."""
+    ring model: all-reduce moves 2(g-1)/g of its bytes, all-gather and
+    all-to-all (g-1)/g, reduce-scatter (g-1) shards."""
     per_op: dict[str, dict] = {}
     wire = 0.0
     for line in hlo_text.splitlines():
@@ -126,7 +121,9 @@ def _make_mesh(multi_pod: bool):
     if dbg:  # e.g. "4,8" or "2,2,8" — development-only shrink
         dims = tuple(int(x) for x in dbg.split(","))
         axes = ("pod", "data", "model")[3 - len(dims):]
-        return jax.make_mesh(dims, axes), f"debug_{dbg.replace(',', 'x')}"
+        mesh = jax.make_mesh(dims, axes,
+                             axis_types=(AxisType.Auto,) * len(dims))
+        return mesh, f"debug_{dbg.replace(',', 'x')}"
     mesh = make_production_mesh(multi_pod=multi_pod)
     return mesh, ("multipod_2x16x16" if multi_pod else "pod_16x16")
 
@@ -265,6 +262,15 @@ def _write(res, out_dir, arch, shape_name, mesh_name, tag):
 
 
 def main():
+    # before the first device query: XLA fixes the device count at start-up
+    os.environ["XLA_FLAGS"] = (
+        os.environ.get("REPRO_XLA_EXTRA", "")
+        + " --xla_force_host_platform_device_count="
+        + os.environ.get("REPRO_DRYRUN_DEVICES", "512")).strip()
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

@@ -519,6 +519,8 @@ class _OnceAction(argparse.Action):
 
 
 def main():
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("notebook", nargs="?", default=None,
                     help=".ipynb path (omit when using --workload)")

@@ -198,6 +198,8 @@ def serve_notebook_fleet(n_sessions: int, *, gpu_capacity: int = 2,
 
 
 def main():
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="demo-100m")
     ap.add_argument("--reduced", action="store_true")

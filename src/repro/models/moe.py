@@ -130,8 +130,7 @@ def moe_ffn_shardmap(p, x, cfg, ctx):
         out = jax.lax.psum(out, "model")
         return out.reshape(Bl, Sl, d)
 
-    from repro.distributed.sharding import shard_map
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(PS(batch, None, None), PS(None, None), w_spec, w_spec, wd_spec),
         out_specs=PS(batch, None, None),

@@ -69,10 +69,13 @@ def ssd_chunked(x, dt, A, B_in, C_in, chunk: int, init_state=None):
 
     # ---- intra-chunk (quadratic in Q) ----
     CB = jnp.einsum("bcin,bcjn->bcij", Cc, Bc, preferred_element_type=jnp.float32)
-    Lmat = jnp.exp(cums[:, :, :, None, :] - cums[:, :, None, :, :])  # (B,nc,i,j,H)
+    # mask the exponent, not the product: above the diagonal the difference
+    # is positive and exp overflows at real chunk sizes, and a masked-out
+    # inf still turns the backward pass into 0 * inf = NaN
     causal = jnp.tril(jnp.ones((Q, Q), bool))
-    M = jnp.where(causal[None, None, :, :, None],
-                  CB[..., None] * Lmat * dtc[:, :, None, :, :], 0.0)
+    seg = cums[:, :, :, None, :] - cums[:, :, None, :, :]           # (B,nc,i,j,H)
+    Lmat = jnp.exp(jnp.where(causal[None, None, :, :, None], seg, -jnp.inf))
+    M = CB[..., None] * Lmat * dtc[:, :, None, :, :]
     y_intra = jnp.einsum("bcijh,bcjhp->bcihp", M.astype(x.dtype), xc)
 
     # ---- chunk states ----

@@ -1,8 +1,7 @@
 """Dry-run plumbing units: HLO collective parsing + cost extrapolation.
 
-(Imports only the pure helpers — importing repro.launch.dryrun would set
-XLA_FLAGS, which must not happen inside the test process; the helpers are
-re-implemented import-free via importlib machinery on the source file.)
+(Loads only the pure helpers, exec'd from the source file, so the test
+process never imports the launcher and the model stack behind it.)
 """
 import importlib.util
 import os
@@ -14,7 +13,7 @@ _SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
 
 def _load_helpers():
     src = open(_SRC).read()
-    # strip the env mutation + jax import side effects: keep pure helpers only
+    # keep the pure helpers only: no jax import, no model stack
     start = src.index("_DTYPE_BYTES")
     end = src.index("def _make_mesh")
     body = src[start:end]

@@ -126,3 +126,19 @@ def test_vocab_padding_never_predicted():
     loss, _ = lm.loss(params, {"tokens": toks})
     assert jnp.isfinite(loss)
     assert cfg.padded_vocab == 512 and cfg.vocab_size == 503
+
+
+def test_ssd_gradients_finite_at_full_chunk_length():
+    """At a real chunk length the decays above the diagonal overflow; the
+    intra-chunk mask must keep them out of the backward pass."""
+    from repro.models.ssm import ssd_chunked
+    k = jax.random.split(jax.random.PRNGKey(3), 4)
+    B, S, H, Pd, N = 1, 256, 2, 4, 8
+    x = jax.random.normal(k[0], (B, S, H, Pd), jnp.float32)
+    dt = jnp.full((B, S, H), 1.0, jnp.float32)       # cumsum reaches -256
+    Bv = jax.random.normal(k[1], (B, S, N), jnp.float32)
+    Cv = jax.random.normal(k[2], (B, S, N), jnp.float32)
+    A = -jnp.ones((H,), jnp.float32)
+    g = jax.grad(lambda x, dt: jnp.sum(
+        ssd_chunked(x, dt, A, Bv, Cv, chunk=256)[0]), argnums=(0, 1))(x, dt)
+    assert all(bool(jnp.all(jnp.isfinite(t))) for t in g)

@@ -131,8 +131,8 @@ def test_quant_roundtrip_bounds(seed, n):
     from repro.kernels.quant_blockwise.ops import dequantize, quantize
     rng = np.random.default_rng(seed)
     x = jnp.asarray(rng.standard_normal(n), jnp.float32)
-    q, s = quantize(x, impl="xla")
-    y = dequantize(q, s, (n,), jnp.float32, impl="xla")
+    q, s = quantize(x)
+    y = dequantize(q, s, (n,), jnp.float32)
     # per-block bound: |err| <= blockmax/127 (+eps)
     assert float(jnp.max(jnp.abs(y - x))) <= float(jnp.max(jnp.abs(x))) / 127 + 1e-6
 

@@ -1,9 +1,7 @@
 """Background delta replication: trickle → bank → claim/cancel lifecycle,
-the unified speculation-waste ledger, liveness pruning, and the degenerate
-case (replication off is bit-identical to the pre-replication decisions)."""
-import json
-import os
-
+the unified speculation-waste ledger and liveness pruning.  The degenerate
+case (replication off reproduces the golden decisions) is
+``test_cost_plane.py::test_fig_decisions_match_golden``."""
 import numpy as np
 import pytest
 
@@ -12,9 +10,6 @@ from repro.core import (
     SessionScheduler,
 )
 from repro.core import telemetry as T
-
-GOLDEN = os.path.join(os.path.dirname(__file__), "data",
-                      "fig_decisions_golden.json")
 
 
 def _replicated_runtime(think_cells=None, **kw):
@@ -198,17 +193,3 @@ def test_scheduler_without_replication_reports_zero_trickle():
     assert rep.trickle_claimed_bytes == 0
 
 
-# -- degenerate case: replication off is the identity ------------------
-
-
-def test_fig_decisions_bit_identical_with_replication_off():
-    """With no replicator attached (the default), the fig5/fig11 decision
-    sweeps must reproduce the committed goldens *bit-identically* — the
-    replication hook must not perturb a single decision or byte count."""
-    from benchmarks import fig5_fig6_policy_speedups, fig11_knowledge_policy
-    with open(GOLDEN) as f:
-        golden = json.load(f)
-    fresh5 = [[n, v, d] for n, v, d in fig5_fig6_policy_speedups.run(smoke=True)]
-    fresh11 = [[n, v, d] for n, v, d in fig11_knowledge_policy.run(smoke=True)]
-    assert fresh5 == golden["fig5_fig6"]
-    assert fresh11 == golden["fig11"]

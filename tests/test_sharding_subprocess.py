@@ -36,7 +36,8 @@ def test_sharded_train_step_runs():
         from repro.optim import init_opt_state
 
         cfg = get_config('yi-6b', reduced=True)
-        mesh = jax.make_mesh((2, 4), ('data', 'model'))
+        mesh = jax.make_mesh((2, 4), ('data', 'model'),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         ctx = DistContext.create(cfg, mesh)
         shape = ShapeConfig('t', 'train', 32, 4)
         lm = LM(cfg, max_seq=33)
@@ -64,7 +65,8 @@ def test_sp_decode_attention_matches_plain():
         from repro.models.attention import cache_write_plain, decode_attention_plain
 
         cfg = get_config('yi-6b', reduced=True)
-        mesh = jax.make_mesh((2, 4), ('data', 'model'))
+        mesh = jax.make_mesh((2, 4), ('data', 'model'),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         ctx = DistContext.create(cfg, mesh)
         B, KV, S, hd, H = 4, 2, 64, 16, 4
         ks = jax.random.split(jax.random.PRNGKey(0), 5)
@@ -99,7 +101,8 @@ def test_shardmap_moe_matches_dense_oracle():
 
         cfg = dataclasses.replace(get_config('qwen3-moe-235b-a22b', reduced=True),
                                   capacity_factor=16.0)  # no drops => exact
-        mesh = jax.make_mesh((2, 4), ('data', 'model'))
+        mesh = jax.make_mesh((2, 4), ('data', 'model'),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         ctx = DistContext.create(cfg, mesh)
         ctx.extra['moe_impl'] = 'shardmap'
         p = init_params(moe_spec(cfg), jax.random.PRNGKey(0), jnp.float32)
@@ -129,7 +132,8 @@ def test_multipod_mesh_dev_scale():
         from repro.models import LM
 
         cfg = get_config('recurrentgemma-9b', reduced=True)
-        mesh = jax.make_mesh((2, 2, 2), ('pod', 'data', 'model'))
+        mesh = jax.make_mesh((2, 2, 2), ('pod', 'data', 'model'),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 3)
         ctx = DistContext.create(cfg, mesh)
         lm = LM(cfg, max_seq=64)
         shape = ShapeConfig('p', 'prefill', 64, 4)
