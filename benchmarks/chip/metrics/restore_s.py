@@ -1,0 +1,6 @@
+"""Mean seconds per cell spent in the benchmark's ``restore`` span (see
+probes.py for the program call it wraps)."""
+
+
+def read(run):
+    return run.spans.get("restore", 0.0) / run.cells if run.cells else None
