@@ -29,6 +29,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from repro import spans
 from repro.core.astdeps import analyze_cell
 from repro.core.context import ContextDetector
 from repro.core.kb import KnowledgeBase, ProvRecord
@@ -752,6 +753,7 @@ class MigrationAnalyzer:
         return n * r_sec, n * r_dol
 
     # ------------------------------------------------------------------
+    @spans.spanned("analyzer.decide")
     def decide(self, nb: Notebook, cell: Cell, *,
                current_env: str | None = None, peek: bool = False) -> Decision:
         """Run the policy chain.  ``peek=True`` skips annotations (used by

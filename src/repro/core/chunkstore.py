@@ -31,6 +31,8 @@ import zlib
 
 import numpy as np
 
+from repro import spans
+
 try:
     import zstandard as _zstd
 except ImportError:  # pragma: no cover
@@ -178,34 +180,39 @@ def array_chunk_digests_many(payloads, chunk_bytes: int = CHUNK_BYTES, *,
         rows = sum(r for _, _, r in pieces)
         # single copy pass: the batch's payload slices land block-padded in
         # one aligned buffer, which the device then aliases or copies
-        host = staging_buffer(rows * _BLOCK_BYTES, np.uint8)
-        off = 0
-        for i, row0, r in pieces:
-            seg = memoryview(payloads[i])[row0 * _BLOCK_BYTES:
-                                          (row0 + r) * _BLOCK_BYTES]
-            end = off + len(seg)
-            host[off:end] = np.frombuffer(seg, dtype=np.uint8)
-            off += r * _BLOCK_BYTES
-            host[end:off] = 0
-        packed = to_device(host)
-        if any(usable[i] for i, _, _ in pieces):
-            prior_lanes = np.zeros((rows, 2), np.uint32)
-            has = np.zeros((rows, 1), np.uint32)
+        with spans.span("hash.stage"):
+            host = staging_buffer(rows * _BLOCK_BYTES, np.uint8)
             off = 0
             for i, row0, r in pieces:
-                if usable[i]:
-                    ph = np.asarray(priors[i][0], np.uint64)[row0:row0 + r]
-                    prior_lanes[off:off + r, 0] = (
-                        ph & np.uint64(0xFFFFFFFF)).astype(np.uint32)
-                    prior_lanes[off:off + r, 1] = (
-                        ph >> np.uint64(32)).astype(np.uint32)
-                    has[off:off + r, 0] = 1
-                off += r
-            h2, ch = jax.device_get(packed_block_digests_compare(
-                packed, jnp.asarray(prior_lanes), jnp.asarray(has)))
+                seg = memoryview(payloads[i])[row0 * _BLOCK_BYTES:
+                                              (row0 + r) * _BLOCK_BYTES]
+                end = off + len(seg)
+                host[off:end] = np.frombuffer(seg, dtype=np.uint8)
+                off += r * _BLOCK_BYTES
+                host[end:off] = 0
+        packed = to_device(host)
+        if any(usable[i] for i, _, _ in pieces):
+            with spans.span("hash.stage"):
+                prior_lanes = np.zeros((rows, 2), np.uint32)
+                has = np.zeros((rows, 1), np.uint32)
+                off = 0
+                for i, row0, r in pieces:
+                    if usable[i]:
+                        ph = np.asarray(priors[i][0],
+                                        np.uint64)[row0:row0 + r]
+                        prior_lanes[off:off + r, 0] = (
+                            ph & np.uint64(0xFFFFFFFF)).astype(np.uint32)
+                        prior_lanes[off:off + r, 1] = (
+                            ph >> np.uint64(32)).astype(np.uint32)
+                        has[off:off + r, 0] = 1
+                    off += r
+            with spans.span("hash.wait"):
+                h2, ch = jax.device_get(packed_block_digests_compare(
+                    packed, jnp.asarray(prior_lanes), jnp.asarray(has)))
             ch = ch[:, 0].astype(bool)
         else:
-            h2 = np.asarray(packed_block_digests(packed))
+            with spans.span("hash.wait"):
+                h2 = np.asarray(packed_block_digests(packed))
             ch = np.ones(rows, bool)
         note_host_sync()
         h64 = _h64(h2)
@@ -216,13 +223,14 @@ def array_chunk_digests_many(payloads, chunk_bytes: int = CHUNK_BYTES, *,
             off += r
 
     out_chunks = []
-    for i in range(n):
-        if lens[i] == 0:
-            out_chunks.append([])
-            continue
-        reuse = priors[i][1] if usable[i] else None
-        out_chunks.append(_fold_chunks(h64s[i], lens[i], chunk_bytes,
-                                       reuse, changed[i]))
+    with spans.span("chunkstore.fold"):
+        for i in range(n):
+            if lens[i] == 0:
+                out_chunks.append([])
+                continue
+            reuse = priors[i][1] if usable[i] else None
+            out_chunks.append(_fold_chunks(h64s[i], lens[i], chunk_bytes,
+                                           reuse, changed[i]))
     return out_chunks, h64s
 
 

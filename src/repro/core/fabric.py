@@ -19,6 +19,7 @@ import time
 from dataclasses import dataclass, replace
 from typing import Iterator
 
+from repro import spans
 from repro.core.chunkstore import DiskChunkStore, MemoryChunkStore
 from repro.core.state import ExecutionState
 
@@ -122,6 +123,7 @@ class ExecutionEnvironment:
         """Preemptible capacity: a non-zero preemption hazard was declared."""
         return self.hazard_rate > 0.0
 
+    @spans.spanned("env.execute")
     def execute(self, source: str, cost: float | None = None) -> float:
         """Run real code against this env's namespace; return modeled seconds."""
         t0 = time.perf_counter()

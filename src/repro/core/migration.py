@@ -20,6 +20,7 @@ from __future__ import annotations
 import math
 from dataclasses import dataclass
 
+from repro import spans
 from repro.core import telemetry as T
 from repro.core.analyzer import Decision, MigrationAnalyzer, PerfModel
 from repro.core.context import ContextDetector
@@ -149,6 +150,7 @@ class MigrationEngine:
         return self._stage_seconds(nbytes) + self._link_seconds(nbytes, src, dst)
 
     # ------------------------------------------------------------------
+    @spans.spanned("engine.migrate")
     def migrate(self, src: ExecutionEnvironment, dst: ExecutionEnvironment,
                 cell_source: str | None = None,
                 names: set[str] | None = None,
@@ -1247,6 +1249,10 @@ class HybridRuntime:
 
     def run_cell(self, ref, *, force_env: str | None = None) -> float:
         """Execute one cell under the policies; returns modeled duration."""
+        with spans.span("runtime.run_cell", cell=ref):
+            return self._run_cell(ref, force_env)
+
+    def _run_cell(self, ref, force_env: str | None) -> float:
         cell = self.nb.cell(ref)
         order = self.nb.order(cell.cell_id)
         t_request = self.clock.now()

@@ -45,6 +45,7 @@ except ImportError:  # pragma: no cover
 import jax
 import jax.numpy as jnp
 
+from repro import spans
 from repro.core.astdeps import cell_dependencies
 from repro.core.chunkstore import (
     CHUNK_BYTES, array_chunk_digests_many, decode_chunk, encode_chunk,
@@ -163,11 +164,15 @@ def host_array(a) -> np.ndarray:
     on that array for as long as the array lives, which would keep a
     second host copy of every captured leaf.  So the pull goes through a
     transient device copy that carries the cache and dies with the result
-    (on the CPU backend ``np.asarray`` aliases and caches nothing)."""
-    if isinstance(a, jax.Array) and any(d.platform != "cpu"
-                                        for d in a.devices()):
-        a = jax.device_put(a, a.sharding, may_alias=False)
-    return np.asarray(a)
+    (on the CPU backend ``np.asarray`` aliases and caches nothing).
+    ``pull_bytes`` counts the array's bytes on every backend."""
+    if not isinstance(a, jax.Array):
+        return np.asarray(a)
+    spans.count("pull_bytes", a.nbytes)
+    with spans.span("reducer.pull"):
+        if any(d.platform != "cpu" for d in a.devices()):
+            a = jax.device_put(a, a.sharding, may_alias=False)
+        return np.asarray(a)
 
 
 def _prepare_array(a, codec: str) -> tuple[dict, bytes]:
@@ -188,11 +193,14 @@ def _prepare_array(a, codec: str) -> tuple[dict, bytes]:
         meta.update(quant=True, block=int(q.shape[1]),
                     scales=_compress(np.asarray(s).tobytes(), codec))
     else:
-        payload = np.ascontiguousarray(host_array(a)).tobytes()
+        a = host_array(a)
+        with spans.span("reducer.payload"):
+            payload = np.ascontiguousarray(a).tobytes()
         meta.update(quant=False)
     return meta, payload
 
 
+@spans.spanned("reducer.assemble")
 def _decode_array(meta: dict, codec: str, chunks: dict[int, bytes],
                   store=None) -> np.ndarray:
     shape = tuple(meta["shape"])
@@ -316,6 +324,7 @@ class StateReducer:
         return needed, modules, info
 
     # -- step 2/3: serialize + digest -----------------------------------
+    @spans.spanned("reducer.capture")
     def serialize_names(self, state: ExecutionState, names,
                         codec: str | None = None,
                         on_error: str = "raise",
@@ -341,20 +350,22 @@ class StateReducer:
         prepared: list[tuple[str, bytes, list]] = []
         for name in sorted(names):
             obj = state.ns[name]
-            try:
-                store: list = []
-                buf = io.BytesIO()
-                _Pickler(buf, store).dump(obj)
-            except Exception as e:  # noqa: BLE001 — paper: fall back to local
-                if on_error == "skip":
-                    skipped.append(name)
-                    continue
-                raise SerializationFailure(f"{name}: {e}") from e
+            with spans.span("reducer.pickle"):
+                try:
+                    store: list = []
+                    buf = io.BytesIO()
+                    _Pickler(buf, store).dump(obj)
+                except Exception as e:  # noqa: BLE001 — paper: fall back
+                    if on_error == "skip":
+                        skipped.append(name)
+                        continue
+                    raise SerializationFailure(f"{name}: {e}") from e
+                stream = _compress(buf.getvalue(), codec)
             # device work (quantize, device->host pull) runs outside the
             # handler: a device error is not the paper's pickling failure
             # and must not quietly turn into a local run
             arrays = [_prepare_array(a, codec) for a in store]
-            prepared.append((name, _compress(buf.getvalue(), codec), arrays))
+            prepared.append((name, stream, arrays))
 
         keys = [(name, k) for name, _, arrs in prepared
                 for k in range(len(arrs))]
@@ -369,39 +380,40 @@ class StateReducer:
             self._chunk_cache[key] = (h64, digs, n)
         del payloads       # each payload is freed once its chunks exist
 
-        pos = 0
-        for name, pickle_bytes, arrays in prepared:
-            digs_here = digest_lists[pos:pos + len(arrays)]
-            pos += len(arrays)
-            # chunks newly inserted by this name; an earlier name's chunks
-            # were inserted under *its* entry, so rolling these back on a
-            # skip can never orphan a previous blob's references
-            added: list[int] = []
-            try:
-                metas = []
-                for k, digests_a in enumerate(digs_here):
-                    meta, payload = arrays[k]
-                    arrays[k] = None     # the chunks below now hold it
-                    clens = []
-                    for d, chunk in zip(digests_a,
-                                        split_chunks(payload,
-                                                     self.chunk_bytes)):
-                        if d not in chunks:
-                            chunks[d] = encode_chunk(chunk, codec)
-                            added.append(d)
-                        # the 1-byte codec tag is store framing, not wire
-                        # payload
-                        clens.append(len(chunks[d]) - 1)
-                    metas.append(dict(meta, chunks=digests_a, clens=clens))
-                blobs[name] = SerializedName(pickle_bytes=pickle_bytes,
-                                             arrays=metas)
-            except Exception as e:  # noqa: BLE001 — paper: fall back to local
-                for d in added:
-                    chunks.pop(d, None)
-                if on_error == "skip":
-                    skipped.append(name)
-                    continue
-                raise SerializationFailure(f"{name}: {e}") from e
+        with spans.span("reducer.encode"):
+            pos = 0
+            for name, pickle_bytes, arrays in prepared:
+                digs_here = digest_lists[pos:pos + len(arrays)]
+                pos += len(arrays)
+                # chunks newly inserted by this name; an earlier name's chunks
+                # were inserted under *its* entry, so rolling these back on a
+                # skip can never orphan a previous blob's references
+                added: list[int] = []
+                try:
+                    metas = []
+                    for k, digests_a in enumerate(digs_here):
+                        meta, payload = arrays[k]
+                        arrays[k] = None     # the chunks below now hold it
+                        clens = []
+                        for d, chunk in zip(digests_a,
+                                            split_chunks(payload,
+                                                         self.chunk_bytes)):
+                            if d not in chunks:
+                                chunks[d] = encode_chunk(chunk, codec)
+                                added.append(d)
+                            # the 1-byte codec tag is store framing, not wire
+                            # payload
+                            clens.append(len(chunks[d]) - 1)
+                        metas.append(dict(meta, chunks=digests_a, clens=clens))
+                    blobs[name] = SerializedName(pickle_bytes=pickle_bytes,
+                                                 arrays=metas)
+                except Exception as e:  # noqa: BLE001 — paper: fall back
+                    for d in added:
+                        chunks.pop(d, None)
+                    if on_error == "skip":
+                        skipped.append(name)
+                        continue
+                    raise SerializationFailure(f"{name}: {e}") from e
         ser = SerializedState(codec=codec, blobs=blobs, chunks=chunks)
         if digests is None:
             ser.digests = self.digest_many({n: state.ns[n] for n in blobs})
@@ -414,6 +426,7 @@ class StateReducer:
         ser.skipped = tuple(skipped)
         return ser
 
+    @spans.spanned("reducer.restore")
     def deserialize(self, ser: SerializedState,
                     target_ns: dict | None = None,
                     chunk_store=None) -> dict[str, Any]:
@@ -547,6 +560,7 @@ class StateReducer:
                                  if n in state.ns})
 
     # -- step 4: delta ---------------------------------------------------
+    @spans.spanned("reducer.delta")
     def delta_names(self, state: ExecutionState, names,
                     known: dict[str, int]):
         """Returns (names to send, tombstones, sender digests).

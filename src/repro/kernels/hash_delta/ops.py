@@ -27,9 +27,14 @@ which is exactly associative/commutative mod 2^32 — ``segment_sum`` over a
 grid, and the host's sum of a split leaf's partial lanes across grids,
 therefore reproduce ``tensor_digest`` bit for bit.
 
-``HOST_SYNCS`` counts device->host materializations issued by this module
-(one per ``tensor_digest``, one per batch) so benchmarks and tests can
-assert the O(leaves) -> O(batches) reduction.
+The hash path's steps are spans and counters of :mod:`repro.spans`:
+``hash.stage`` (host copies into the staging buffer), ``hash.upload`` and
+``upload_bytes`` (:func:`to_device`), ``hash.wait`` (launch, device time
+and the lanes' return), ``widened_bytes`` (the uint32 grid each hash
+program reads, counted at its launch) and ``host_syncs`` (device->host
+materializations: one per ``tensor_digest``, one per batch).
+``HOST_SYNCS`` reads ``host_syncs`` since :func:`reset_host_syncs`, so
+benchmarks and tests can assert the O(leaves) -> O(batches) reduction.
 """
 from __future__ import annotations
 
@@ -40,6 +45,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.kernels.hash_delta.kernel import (
     block_hash_compare_kernel, block_hash_kernel,
 )
@@ -50,23 +56,34 @@ LANES = 2
 BATCH_ROWS = 1 << 16            # block rows per launch: 256 MiB of uint32
 _FOLD = np.uint32(2246822519)   # per-block position weight (golden-prime)
 
-HOST_SYNCS = 0                  # device->host syncs since reset_host_syncs()
+_sync_base = 0                  # the ``host_syncs`` count at the last reset
 
 
 def _note_sync(n: int = 1) -> None:
-    global HOST_SYNCS
-    HOST_SYNCS += n
+    spans.count("host_syncs", n)
 
 
 def reset_host_syncs() -> None:
-    global HOST_SYNCS
-    HOST_SYNCS = 0
+    global _sync_base
+    _sync_base = spans.total("host_syncs")
 
 
 def note_host_sync(n: int = 1) -> None:
     """Record a device->host sync issued by a caller (e.g. the chunk
     store's batched fold pulling the packed digest vector)."""
     _note_sync(n)
+
+
+def _note_launch(rows: int) -> None:
+    """Count the uint32 grid of ``rows`` blocks a hash program reads."""
+    spans.count("widened_bytes", rows * BLOCK * 4)
+
+
+def __getattr__(name: str):
+    if name == "HOST_SYNCS":    # device->host syncs since reset_host_syncs()
+        return spans.total("host_syncs") - _sync_base
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
+
 
 # host constants (no tracer leak): one odd weight vector per lane.  Lane 0
 # keeps the historical 0xD1657 stream; lane 1 is an independent stream.
@@ -130,6 +147,7 @@ def block_digests(x):
     One row per 1024-element block; the two lanes together are the block's
     64-bit identity.  Only this (nb, 2) vector crosses to the host — never
     the tensor itself."""
+    _note_launch(-(-x.size // BLOCK))
     return _block_lanes(x, mode=kernel_mode(x))
 
 
@@ -144,7 +162,9 @@ def _digest_lanes(x, *, mode: str):
 
 def tensor_digest(x, *, interpret: bool = False) -> int:
     """Any tensor -> one 64-bit int digest (content hash for delta migration)."""
-    lo, hi = np.asarray(_digest_lanes(x, mode=kernel_mode(x, interpret)))
+    _note_launch(-(-int(np.size(x)) // BLOCK))
+    with spans.span("hash.wait"):
+        lo, hi = np.asarray(_digest_lanes(x, mode=kernel_mode(x, interpret)))
     _note_sync()
     return (int(hi) << 32) | int(lo)
 
@@ -166,6 +186,7 @@ def packed_block_digests(u8):
     The caller has already zero-padded each constituent payload to its own
     block boundary, so every row equals the row :func:`block_digests` would
     produce for that payload standalone."""
+    _note_launch(u8.size // BLOCK)
     return _packed_lanes(u8, mode=kernel_mode(u8))
 
 
@@ -173,6 +194,7 @@ def packed_block_digests_compare(u8, prior, has_prior):
     """Fused variant of :func:`packed_block_digests`: digest + compare
     against a prior (nb, 2) lane vector in one launch; returns
     ``(h, changed)`` with ``changed`` (nb, 1) uint32."""
+    _note_launch(u8.size // BLOCK)
     return _packed_lanes_compare(u8, prior, has_prior, mode=kernel_mode(u8))
 
 
@@ -201,13 +223,15 @@ def to_device(a: np.ndarray):
     Where that device is the CPU, the result aliases ``a`` (zero-copy when
     64-byte aligned), so callers must not mutate ``a`` while it is in use.
     Elsewhere the buffer is copied to the device."""
-    dev = jax.devices()[0]
-    if dev.platform == "cpu":
-        try:
-            return jnp.from_dlpack(a)
-        except (TypeError, ValueError, RuntimeError):
-            pass     # not shareable: fall through to a copying transfer
-    return jax.device_put(a, dev)
+    spans.count("upload_bytes", a.nbytes)
+    with spans.span("hash.upload"):
+        dev = jax.devices()[0]
+        if dev.platform == "cpu":
+            try:
+                return jnp.from_dlpack(a)
+            except (TypeError, ValueError, RuntimeError):
+                pass     # not shareable: fall through to a copying transfer
+        return jax.device_put(a, dev)
 
 
 _STAGING = threading.local()
@@ -306,12 +330,13 @@ def _pack_batch(leaves, pieces, split: dict):
     for i, row0, rows in pieces:
         a = leaves[i]
         if _is_host(a):
-            flat = _np_u32_flat(
-                np.ravel(np.asarray(a))[row0 * BLOCK:(row0 + rows) * BLOCK])
-            end = off + flat.size
-            dst[off:end] = flat
-            off += rows * BLOCK
-            dst[end:off] = 0
+            with spans.span("hash.stage"):
+                flat = _np_u32_flat(np.ravel(np.asarray(a))[
+                    row0 * BLOCK:(row0 + rows) * BLOCK])
+                end = off + flat.size
+                dst[off:end] = flat
+                off += rows * BLOCK
+                dst[end:off] = 0
             continue
         _close_run()
         run_lo = off
@@ -369,12 +394,15 @@ def digest_leaves(leaves, *, interpret: bool = False) -> list[int]:
     for pieces in plan_batches([-(-int(np.size(a)) // BLOCK)
                                 for a in leaves]):
         x2d = _pack_batch(leaves, pieces, split)
-        idx, seg = _fold_weights(pieces)
+        with spans.span("hash.stage"):
+            idx, seg = _fold_weights(pieces)
         # bucket the segment count so batches share compilations
         nseg = max(8, 1 << (len(pieces) - 1).bit_length())
-        lanes = np.asarray(_batched_lanes(
-            x2d, jnp.asarray(idx), jnp.asarray(seg), num_segments=nseg,
-            mode=kernel_mode(x2d, interpret)))
+        _note_launch(x2d.shape[0])
+        with spans.span("hash.wait"):
+            lanes = np.asarray(_batched_lanes(
+                x2d, jnp.asarray(idx), jnp.asarray(seg), num_segments=nseg,
+                mode=kernel_mode(x2d, interpret)))
         _note_sync()
         # a leaf has at most one piece per batch; uint32 adds wrap mod 2^32
         totals[[i for i, _, _ in pieces]] += lanes[:len(pieces)]
