@@ -204,8 +204,12 @@ class AsyncCheckpointer:
 
     def save(self, step: int, trees: dict) -> None:
         self.wait()
-        # snapshot to host first (cheap on CPU; device_get on TPU)
-        host = jax.tree_util.tree_map(np.asarray, trees)
+        # snapshot to host first (cheap on CPU; device_get on TPU).  Host
+        # numpy leaves are copied: the capture reads arrays in place, and
+        # the caller may change them while the writer runs
+        host = jax.tree_util.tree_map(
+            lambda x: x.copy() if isinstance(x, np.ndarray) else np.asarray(x),
+            trees)
 
         def run():
             self.last_info = self.inner.save(step, host)

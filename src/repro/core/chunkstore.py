@@ -85,8 +85,9 @@ def effective_chunk_bytes(n: int, chunk_bytes: int) -> int:
     return max(_BLOCK_BYTES, chunk_bytes - chunk_bytes % _BLOCK_BYTES)
 
 
-def split_chunks(data: bytes, chunk_bytes: int = CHUNK_BYTES) -> list[bytes]:
-    """Fixed-size split; the final chunk may be short."""
+def split_chunks(data, chunk_bytes: int = CHUNK_BYTES) -> list:
+    """Fixed-size split; the final chunk may be short.  Slices of a
+    ``memoryview`` are views of its buffer; slices of ``bytes`` copy."""
     n = len(data)
     if n == 0:
         return []
@@ -238,10 +239,12 @@ def array_chunk_digests_many(payloads, chunk_bytes: int = CHUNK_BYTES, *,
 # chunk encoding (codec-tagged, self-describing)
 # ----------------------------------------------------------------------
 
-def encode_chunk(raw: bytes, codec: str) -> bytes:
-    """Raw chunk -> 1-byte codec tag + compressed bytes.  The tag records
-    what was *actually* used (zstd falls back to zlib when unavailable), so
-    decoding never depends on the requesting serialization's codec."""
+def encode_chunk(raw, codec: str) -> bytes:
+    """Raw chunk (``bytes`` or any byte buffer, such as a ``memoryview``)
+    -> 1-byte codec tag + compressed bytes, as new ``bytes``.  The tag
+    records what was *actually* used (zstd falls back to zlib when
+    unavailable), so decoding never depends on the requesting
+    serialization's codec."""
     if codec == "none":
         return bytes([_CODEC_IDS["none"]]) + raw
     if codec in ("zstd", "quant8+zstd") and _zstd is not None:
@@ -250,8 +253,16 @@ def encode_chunk(raw: bytes, codec: str) -> bytes:
 
 
 def decode_chunk(data: bytes) -> bytes:
+    body = chunk_body(data)
+    return body.tobytes() if isinstance(body, memoryview) else body
+
+
+def chunk_body(data) -> memoryview | bytes:
+    """The raw bytes of an encoded chunk without copying what need not be
+    copied: a codec-``none`` body is a read-only view into ``data``; a
+    compressed body is decompressed from a view into new ``bytes``."""
     codec = _CODEC_NAMES[data[0]]
-    body = data[1:]
+    body = memoryview(data)[1:]
     if codec == "none":
         return body
     if codec == "zstd":

@@ -48,7 +48,7 @@ import jax.numpy as jnp
 from repro import spans
 from repro.core.astdeps import cell_dependencies
 from repro.core.chunkstore import (
-    CHUNK_BYTES, array_chunk_digests_many, decode_chunk, encode_chunk,
+    CHUNK_BYTES, array_chunk_digests_many, chunk_body, encode_chunk,
     split_chunks,
 )
 from repro.core.state import ExecutionState
@@ -175,10 +175,28 @@ def host_array(a) -> np.ndarray:
         return np.asarray(a)
 
 
-def _prepare_array(a, codec: str) -> tuple[dict, bytes]:
+def _byte_view(a: np.ndarray):
+    """The bytes of ``a`` in C order as a flat ``uint8`` view, copied only
+    where ``a`` is not C-contiguous; ``tobytes()`` where its dtype cannot
+    be viewed as bytes (object arrays).  Each copy counts in
+    ``copied_bytes``."""
+    c = np.ascontiguousarray(a)
+    try:
+        view = c.reshape(-1).view(np.uint8)
+    except (TypeError, ValueError):
+        spans.count("copied_bytes", c.nbytes)
+        return c.tobytes()
+    if not a.flags.c_contiguous:
+        spans.count("copied_bytes", c.nbytes)
+    return view
+
+
+def _prepare_array(a, codec: str) -> tuple[dict, Any]:
     """Array (host or device) -> (chunk-manifest meta sans digests, raw
-    payload bytes).  A device array is quantized where it lives, then
-    pulled to the host.
+    payload).  A device array is quantized where it lives, then pulled to
+    the host.  The payload is a byte view of the host array where one
+    exists (:func:`_byte_view`): a pulled device array is private to the
+    capture, and a host array is the namespace's own, read in place.
 
     Digesting is deferred so the caller can batch every payload of a
     capture into one device launch (:func:`array_chunk_digests_many`)."""
@@ -195,7 +213,7 @@ def _prepare_array(a, codec: str) -> tuple[dict, bytes]:
     else:
         a = host_array(a)
         with spans.span("reducer.payload"):
-            payload = np.ascontiguousarray(a).tobytes()
+            payload = _byte_view(a)
         meta.update(quant=False)
     return meta, payload
 
@@ -206,23 +224,36 @@ def _decode_array(meta: dict, codec: str, chunks: dict[int, bytes],
     shape = tuple(meta["shape"])
     dtype = np.dtype(meta["dtype"]) if meta["dtype"] != "bfloat16" else jnp.bfloat16.dtype
 
-    def fetch(d: int) -> bytes:
+    def fetch(d: int):
         if d in chunks:
-            return decode_chunk(chunks[d])
+            return chunk_body(chunks[d])
         if store is not None and store.has(d):
-            return decode_chunk(store.get(d))
+            return chunk_body(store.get(d))
         raise KeyError(f"missing chunk {d:016x}")
 
-    raw = b"".join(fetch(d) for d in meta["chunks"])
     if meta["quant"]:
         from repro.kernels.quant_blockwise.ops import dequantize
+        raw = b"".join(fetch(d) for d in meta["chunks"])
         block = int(meta["block"])   # quant block size travels in the meta
         q = np.frombuffer(raw, np.int8).reshape(-1, block)
         s = np.frombuffer(_decompress(meta["scales"], codec), np.float32)
         x = dequantize(jnp.asarray(q), jnp.asarray(s), shape,
                        jnp.dtype(dtype))
         return np.asarray(x)
-    return np.frombuffer(raw, dtype).reshape(shape).copy()
+    # one copy: each chunk body lands at its offset in the new array (a
+    # body that runs past the end fails the slice assignment)
+    out = np.empty(shape, dtype)
+    dst = memoryview(out.reshape(-1).view(np.uint8))
+    pos = 0
+    for d in meta["chunks"]:
+        body = fetch(d)
+        dst[pos:pos + len(body)] = body
+        pos += len(body)
+    if pos != len(dst):
+        raise ValueError(f"chunks of a {shape} {dtype} array hold {pos} of "
+                         f"its {len(dst)} bytes")
+    spans.count("copied_bytes", pos)
+    return out
 
 
 # ----------------------------------------------------------------------
@@ -394,16 +425,25 @@ class StateReducer:
                     for k, digests_a in enumerate(digs_here):
                         meta, payload = arrays[k]
                         arrays[k] = None     # the chunks below now hold it
+                        # the one copy of a payload: views of it, each
+                        # encoded into new bytes.  A host leaf's payload is
+                        # the namespace's live array, so the bytes stored
+                        # are those its chunk keys were computed from:
+                        # capture runs on the caller's thread, and nothing
+                        # runs the notebook between passes 1 and 3
                         clens = []
+                        encoded = 0
                         for d, chunk in zip(digests_a,
-                                            split_chunks(payload,
+                                            split_chunks(memoryview(payload),
                                                          self.chunk_bytes)):
                             if d not in chunks:
                                 chunks[d] = encode_chunk(chunk, codec)
                                 added.append(d)
+                                encoded += len(chunk)
                             # the 1-byte codec tag is store framing, not wire
                             # payload
                             clens.append(len(chunks[d]) - 1)
+                        spans.count("copied_bytes", encoded)
                         metas.append(dict(meta, chunks=digests_a, clens=clens))
                     blobs[name] = SerializedName(pickle_bytes=pickle_bytes,
                                                  arrays=metas)

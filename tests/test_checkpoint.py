@@ -69,6 +69,17 @@ def test_async_checkpointer(tmp_path):
     assert step == 1
 
 
+def test_async_checkpointer_saves_host_arrays_as_they_were_at_save(tmp_path):
+    ck = AsyncCheckpointer(Checkpointer(str(tmp_path)))
+    host = np.arange(300000, dtype=np.float32)
+    ck.save(1, {"state": {"h": host}})
+    host[:] = -1.0                 # while the writer may still be capturing
+    ck.wait()
+    out, _ = ck.inner.restore({"state": {"h": host}})
+    np.testing.assert_array_equal(out["state"]["h"],
+                                  np.arange(300000, dtype=np.float32))
+
+
 def test_gc_rebase_chain(tmp_path):
     ck = Checkpointer(str(tmp_path), keep=2, rebase_every=5)
     for s in range(1, 7):
