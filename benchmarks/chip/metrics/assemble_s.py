@@ -1,6 +1,7 @@
 """Mean seconds per cell in the program span ``reducer.assemble``:
-fetching, decoding and joining a restored array's chunks
-(core/reducer.py)."""
+fetching each chunk of a restored array (a view of a codec-``none`` body,
+decompressed bytes otherwise) and filling the destination array in place;
+quantized arrays are joined and dequantized instead (core/reducer.py)."""
 from program_spans import span_seconds
 
 

@@ -1,12 +1,15 @@
-"""The comparison fails the lower-precision control and planted faults.
+"""The comparison fails the lower-precision controls and planted faults.
 
 The control is the program's own lossy path, the ``quant8+zstd`` codec
-(int8 blocks for the bfloat16 and float32 state).  The faults are planted
+(int8 blocks for the bfloat16 and float32 state), and, for state that
+path cannot carry on the chip, every restore handed back one precision
+down (``control.round_restores``).  The faults are planted
 in the program when the window opens: a migration that leaves the
 destination as it was, a delta that leaves out half of the changed names,
 a restored value with one bit altered, and a leaf digest altered where the
 kernel path returns it."""
 import chip_tiny
+import control
 import jax
 import numpy as np
 import pytest
@@ -15,6 +18,14 @@ import pytest
 @pytest.mark.parametrize("workload", chip_tiny.WORKLOADS)
 def test_lower_precision_control_is_not_correct(workload):
     r = chip_tiny.run(workload, codec="quant8+zstd")
+    assert r["correct"] is False
+    assert r["checks"]["restore_bad"]["value"] > 0 \
+        or r["checks"]["output_bad"]["value"] > 0
+
+
+@pytest.mark.parametrize("workload", chip_tiny.WORKLOADS)
+def test_rounded_restore_control_is_not_correct(workload):
+    r = chip_tiny.run(workload, fault=control.round_restores)
     assert r["correct"] is False
     assert r["checks"]["restore_bad"]["value"] > 0 \
         or r["checks"]["output_bad"]["value"] > 0
