@@ -13,6 +13,19 @@ from typing import Any
 
 VOCAB_PAD_MULTIPLE = 256  # keeps every padded vocab divisible by the model axis
 
+# a published ``layer_types`` entry -> the decoder's block kind
+LAYER_TYPE_KINDS = {"mamba": "ssm", "attention": "attn"}
+
+
+def period_pattern(layer_types) -> tuple[str, ...]:
+    """The shortest period of a published ``layer_types`` list, as block
+    kinds: the ``block_pattern`` whose repetition gives every layer."""
+    kinds = [LAYER_TYPE_KINDS[t] for t in layer_types]
+    for p in range(1, len(kinds) + 1):
+        if all(k == kinds[i % p] for i, k in enumerate(kinds)):
+            return tuple(kinds[:p])
+    raise ValueError("empty layer_types")
+
 
 @dataclass(frozen=True)
 class ModelConfig:
@@ -29,18 +42,30 @@ class ModelConfig:
     # --- positional / norm ---
     rope_theta: float = 10_000.0
     rope_pct: float = 1.0            # stablelm uses partial rotary
-    pos_embed: str = "rope"          # rope | learned
+    pos_embed: str = "rope"          # rope | learned | nope (none at all)
     norm_eps: float = 1e-5
     qk_norm: bool = False            # qwen3 style RMSNorm on q,k heads
     tie_embeddings: bool = False
 
     # --- MoE ---
-    num_experts: int = 0
+    num_experts: int = 0             # experts held (all of them, or a share)
     experts_per_tok: int = 0
     shared_expert_d_ff: int = 0      # qwen2-moe shared expert
+    shared_expert_gated: bool = True  # sigmoid gate (qwen2-moe); granite: none
     norm_topk_prob: bool = False
     capacity_factor: float = 1.25
     router_aux_coef: float = 0.001   # load-balance auxiliary loss
+    # expert share (expert parallelism): the router spans ``router_experts``
+    # and this layer holds experts [first_expert, first_expert+num_experts)
+    router_experts: int = 0          # 0: the router spans the held experts
+    first_expert: int = 0
+    dropless: bool = False           # no capacity: every routed token counts
+
+    # --- granite scalings (1.0 / 0.0 leave a layer as it was) ---
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0  # on each residual branch
+    attention_multiplier: float = 0.0  # score scale; 0: 1/sqrt(head_dim)
+    logits_scaling: float = 1.0       # logits are divided by it
 
     # --- SSM (mamba2 / SSD) ---
     ssm_state: int = 0
@@ -48,6 +73,7 @@ class ModelConfig:
     ssm_headdim: int = 64
     ssm_chunk: int = 256
     conv_width: int = 4
+    conv_bias: bool = False
 
     # --- hybrid (recurrentgemma) ---
     block_pattern: tuple[str, ...] = ()   # e.g. ("rec", "rec", "attn")
@@ -92,6 +118,10 @@ class ModelConfig:
         return self.d_inner // self.ssm_headdim if self.ssm_state else 0
 
     @property
+    def num_router_experts(self) -> int:
+        return self.router_experts or self.num_experts
+
+    @property
     def is_attention_free(self) -> bool:
         return self.family == "ssm"
 
@@ -126,16 +156,32 @@ class ModelConfig:
     def _ffn_params_dense(self, d_ff: int) -> int:
         return 3 * self.d_model * d_ff  # SwiGLU: gate, up, down
 
-    def _layer_params(self, kind: str) -> int:
+    def _moe_params(self, active: bool = False) -> int:
+        """Routed experts (the held ones, or the active ones), router and
+        shared expert."""
+        n = self.experts_per_tok if active else self.num_experts
+        ffn = n * self._ffn_params_dense(self.d_ff)
+        ffn += self.d_model * self.num_router_experts  # router
+        if self.shared_expert_d_ff:
+            ffn += self._ffn_params_dense(self.shared_expert_d_ff)
+            ffn += self.d_model * self.shared_expert_gated
+        return ffn
+
+    def _layer_params(self, kind: str, active: bool = False) -> int:
         d = self.d_model
         norms = 2 * d
         if kind == "ssm":
             din, ns = self.d_inner, self.ssm_state
             in_proj = d * (2 * din + 2 * ns + self.ssm_heads)
-            conv = (din + 2 * ns) * self.conv_width
+            conv = (din + 2 * ns) * (self.conv_width + self.conv_bias)
             extra = 3 * self.ssm_heads  # A_log, D, dt_bias
             out = din * d + din  # out_proj + gated norm
-            return in_proj + conv + extra + out + d  # single pre-norm
+            mixer = in_proj + conv + extra + out + d  # pre-norm
+            if not self.d_ff:  # mamba2: the mixer is the whole layer
+                return mixer
+            if self.num_experts:
+                return mixer + d + self._moe_params(active)
+            return mixer + d + self._ffn_params_dense(self.d_ff)
         if kind == "rec":
             w = self.lru_width
             in_proj = 2 * d * w            # x and gate branches
@@ -147,23 +193,12 @@ class ModelConfig:
             return in_proj + conv + lru + lru_gates + out + ffn + norms
         # attention-bearing layer
         attn = self._attn_params()
-        if kind == "attn" and self.family == "moe":
-            ffn = self.num_experts * self._ffn_params_dense(self.d_ff)
-            ffn += self.d_model * self.num_experts  # router
-            if self.shared_expert_d_ff:
-                ffn += self._ffn_params_dense(self.shared_expert_d_ff) + self.d_model
-            return attn + ffn + norms
+        if self.num_experts:
+            return attn + self._moe_params(active) + norms
         return attn + self._ffn_params_dense(self.d_ff) + norms
 
     def _active_layer_params(self, kind: str) -> int:
-        if kind == "attn" and self.family == "moe":
-            attn = self._attn_params()
-            ffn = self.experts_per_tok * self._ffn_params_dense(self.d_ff)
-            ffn += self.d_model * self.num_experts
-            if self.shared_expert_d_ff:
-                ffn += self._ffn_params_dense(self.shared_expert_d_ff) + self.d_model
-            return attn + ffn + 2 * self.d_model
-        return self._layer_params(kind)
+        return self._layer_params(kind, active=True)
 
     def count_params(self, active_only: bool = False) -> int:
         """Analytic parameter count (embeddings use the *unpadded* vocab)."""

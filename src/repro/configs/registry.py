@@ -17,6 +17,7 @@ _ARCH_MODULES: dict[str, str] = {
     "internvl2-2b": "internvl2_2b",
     "whisper-tiny": "whisper_tiny",
     "recurrentgemma-9b": "recurrentgemma_9b",
+    "granite-4.0-h-small": "granite_4_0_h_small",
     "demo-100m": "demo_100m",
 }
 

@@ -253,6 +253,9 @@ class MigrationEngine:
                                                 chunk_store=dst_store)
                 dst.state.update(objs)
         dst.state.drop(dead)
+        # chunks of the sent names that the receiver already held: looked
+        # at, but they did not cross
+        spans.count("held_bytes", sum(len(ser.chunks[d]) - 1 for d in held))
 
         known.update(ser.digests)
         for n in dead:

@@ -521,17 +521,24 @@ class StateReducer:
 
     def _host_digest(self, obj) -> int:
         """Pickle-stream blake2b for objects that are not pure array trees."""
+        return self._host_digest_sized(obj)[0]
+
+    @staticmethod
+    def _host_digest_sized(obj) -> tuple[int, int]:
+        """(:meth:`_host_digest`, bytes it read: the pickle stream and the
+        arrays taken out of it)."""
         try:
             store: list = []
             buf = io.BytesIO()
             _Pickler(buf, store).dump(obj)
         except Exception:
-            return -1  # unhashable => always migrate (paper §II-D)
+            return -1, 0  # unhashable => always migrate (paper §II-D)
         h = hashlib.blake2b(buf.getvalue(), digest_size=8)
         for a in store:
             h.update(np.ascontiguousarray(host_array(a)).tobytes())
             h.update(str(a.shape).encode())
-        return int.from_bytes(h.digest(), "little")
+        nbytes = buf.getbuffer().nbytes + sum(a.nbytes for a in store)
+        return int.from_bytes(h.digest(), "little"), nbytes
 
     def digest(self, obj) -> int:
         if _is_array(obj):
@@ -609,11 +616,17 @@ class StateReducer:
         Pure-array names ride the fused digest->compare->gather path: the
         fresh digests are compared against ``known`` on device and only the
         changed-name index list crosses to the host — one launch, one sync
-        for the whole manifest."""
+        for the whole manifest.
+
+        Counts ``kept_bytes``: the bytes of the names digested here that
+        the receiver already knows, so that they are not sent (array leaves
+        at their size, other objects at their pickle stream's)."""
         from repro.kernels.hash_delta.ops import digest_leaves_delta
         objs = {n: state.ns[n] for n in names if n in state.ns}
         slots, leaves, host = self._split_for_batch(objs)
-        here = {n: self._host_digest(o) for n, o in host.items()}
+        sized = {n: self._host_digest_sized(o) for n, o in host.items()}
+        here = {n: d for n, (d, _) in sized.items()}
+        size = {n: b for n, (_, b) in sized.items()}
         send = {n for n, d in here.items() if d == -1 or known.get(n) != d}
         if slots:
             # per-leaf priors: a single-array name compares on device
@@ -636,5 +649,10 @@ class StateReducer:
             send.update(leaf_name[j] for j in changed if j in leaf_name)
             send.update(n for n, treedef, _k in slots
                         if treedef is not None and known.get(n) != folded[n])
+            size.update((n, sum(a.nbytes for a in
+                                jax.tree_util.tree_leaves(objs[n])))
+                        for n, _t, _k in slots)
+        spans.count("kept_bytes", sum(b for n, b in size.items()
+                                      if n not in send))
         dead = {n for n in known if n not in state.ns}
         return send, dead, here

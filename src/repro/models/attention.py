@@ -51,7 +51,8 @@ def _group(q, KV):
     return q.reshape(B, S, KV, H // KV, hd)
 
 
-def full_causal_attention(q, k, v, *, chunk_q: int = 1024):
+def full_causal_attention(q, k, v, *, chunk_q: int = 1024,
+                          scale: float | None = None):
     """Chunked causal attention (flash-style at the XLA level).
 
     Scans over query chunks so the (chunk, S) score block is the only
@@ -63,7 +64,7 @@ def full_causal_attention(q, k, v, *, chunk_q: int = 1024):
     B, S, H, hd = q.shape
     KV = k.shape[2]
     qg = _group(q, KV)                      # (B,S,KV,G,hd)
-    scale = hd ** -0.5
+    scale = scale or hd ** -0.5
     nq = max(S // min(chunk_q, S), 1)
     cq = S // nq
     qb = qg.reshape(B, nq, cq, KV, H // KV, hd)
@@ -118,14 +119,16 @@ def banded_local_attention(q, k, v, *, window: int):
 # Decode (one token, cache) — plain path.  SP path: distributed/decode_attn.
 # ----------------------------------------------------------------------
 
-def decode_attention_plain(q, k_cache, v_cache, pos):
+def decode_attention_plain(q, k_cache, v_cache, pos, scale: float | None = None):
     """q (B,1,H,hd); caches (B,KV,S,hd); pos (B,) index of the CURRENT token
-    (caches already contain the current token at ``pos``)."""
+    (caches already contain the current token at ``pos``).  ``scale``
+    multiplies the scores (default 1/sqrt(hd))."""
     B, _, H, hd = q.shape
     KV = k_cache.shape[1]
     S = k_cache.shape[2]
     qg = q.reshape(B, KV, H // KV, hd)
-    s = jnp.einsum("bkgh,bksh->bkgs", qg, k_cache).astype(jnp.float32) * (hd ** -0.5)
+    s = jnp.einsum("bkgh,bksh->bkgs", qg, k_cache).astype(jnp.float32) * (
+        scale or hd ** -0.5)
     valid = jnp.arange(S)[None, :] <= pos[:, None]      # (B,S)
     s = jnp.where(valid[:, None, None, :], s, NEG)
     w = jax.nn.softmax(s, axis=-1).astype(v_cache.dtype)

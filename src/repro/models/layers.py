@@ -149,8 +149,8 @@ def apply_rope(x, positions, *, rope_pct: float = 1.0, theta: float = 10_000.0):
 # Depthwise causal conv1d (mamba2 / RG-LRU frontends)
 # ----------------------------------------------------------------------
 
-def causal_conv1d(x, w, state=None, activation: bool = True):
-    """x: (B, S, C); w: (C, W) depthwise causal filter.
+def causal_conv1d(x, w, state=None, activation: bool = True, bias=None):
+    """x: (B, S, C); w: (C, W) depthwise causal filter; bias (C,) or None.
 
     Returns (y, new_state) where state (B, W-1, C) carries the last W-1 inputs
     (used for decode).  Training path pads with zeros (state None).
@@ -168,6 +168,8 @@ def causal_conv1d(x, w, state=None, activation: bool = True):
     y = jnp.zeros_like(x)
     for k in range(W):
         y = y + xp[:, k:k + S, :] * w[:, k].astype(x.dtype)
+    if bias is not None:
+        y = y + bias.astype(x.dtype)
     new_state = xp[:, S:, :] if W > 1 else jnp.zeros((B, 0, C), x.dtype)
     return (silu(y) if activation else y), new_state
 

@@ -57,11 +57,15 @@ class LM:
     # ------------------------------------------------------------------
     def _embed_in(self, params, tokens, ctx):
         x = embed_lookup(params["embed"], tokens)
+        if self.cfg.embedding_multiplier != 1.0:
+            x = x * self.cfg.embedding_multiplier
         return shard(ctx, x, "batch", "seq", None)
 
     def _logits(self, params, x, ctx):
         table = params["embed"] if self.cfg.tie_embeddings else params["w_out"]
         out = logits_from_embed(x, table)
+        if self.cfg.logits_scaling != 1.0:
+            out = out / self.cfg.logits_scaling
         return shard(ctx, out, "batch", "seq", "vocab")
 
     # ------------------------------------------------------------------

@@ -6,6 +6,8 @@ dispatch matmul is quadratic in tokens) we sort token->expert assignments,
 expert einsum (the only real FLOPs, ~= active-param FLOPs x capacity factor),
 and combine with router weights.  Tokens beyond an expert's capacity are
 dropped (standard practice; aux loss keeps the router balanced).
+``cfg.dropless`` (granite) takes :func:`moe_dropless` instead: no
+capacity, and the layer may hold a share of a wider router's experts.
 
 Sharding: EP mode shards the E axis over "model" (qwen3: 128 experts);
 expert-TP mode shards each expert's hidden dim (qwen2: 60 experts, 60 % 16 != 0).
@@ -22,7 +24,8 @@ from repro.models.layers import P, silu, swiglu
 def moe_spec(cfg):
     d, E, ff = cfg.d_model, cfg.num_experts, cfg.d_ff
     spec = {
-        "router": P((d, E), ("embed", "experts"), scale=0.02),
+        "router": P((d, cfg.num_router_experts), ("embed", "experts"),
+                    scale=0.02),
         "w_gate": P((E, d, ff), ("experts", "embed", "expert_mlp")),
         "w_up": P((E, d, ff), ("experts", "embed", "expert_mlp")),
         "w_down": P((E, ff, d), ("experts", "expert_mlp", "embed")),
@@ -33,9 +36,61 @@ def moe_spec(cfg):
             "w_gate": P((d, sf), ("embed", "mlp")),
             "w_up": P((d, sf), ("embed", "mlp")),
             "w_down": P((sf, d), ("mlp", "embed")),
-            "gate": P((d, 1), ("embed", None), scale=0.02),
         }
+        if cfg.shared_expert_gated:
+            spec["shared"]["gate"] = P((d, 1), ("embed", None), scale=0.02)
     return spec
+
+
+def shared_expert(sp, xt, cfg):
+    """The shared expert on tokens ``xt`` (T, d): a SwiGLU, under a sigmoid
+    gate where the configuration has one (qwen2-moe; granite has none)."""
+    y = swiglu(xt, sp["w_gate"], sp["w_up"], sp["w_down"])
+    if not cfg.shared_expert_gated:
+        return y
+    sgate = jax.nn.sigmoid(jnp.einsum("td,do->to", xt, sp["gate"]
+                                      ).astype(jnp.float32))
+    return sgate.astype(xt.dtype) * y
+
+
+def _aux_loss(logits, idx, cfg):
+    """Switch-style load-balance loss over the router's experts."""
+    E = logits.shape[-1]
+    probs = jax.nn.softmax(logits, axis=-1)
+    me = jnp.mean(probs, axis=0)
+    ce = jnp.mean(jax.nn.one_hot(idx, E, dtype=jnp.float32), axis=0)
+    return cfg.router_aux_coef * E * jnp.sum(me * ce)
+
+
+def moe_dropless(p, x, cfg):
+    """Dropless MoE over the experts this layer holds (granite).
+
+    The router spans all ``cfg.num_router_experts`` experts: each token
+    keeps its top ``experts_per_tok`` logits, and its gates are the softmax
+    over those (the same as ``norm_topk_prob``).  The layer holds experts
+    ``[first_expert, first_expert + num_experts)`` and computes the part of
+    the result that they give: each held expert runs on every token, and
+    its output is weighted by the token's gate for it, zero where the token
+    did not route to it, so no routed token is dropped however uneven the
+    routing.  Under expert parallelism the shares of all chips add up to
+    the whole layer; the shared expert is every chip's alike.
+    """
+    B, S, d = x.shape
+    xt = x.reshape(B * S, d)
+    logits = jnp.einsum("td,de->te", xt, p["router"]).astype(jnp.float32)
+    top, idx = jax.lax.top_k(logits, cfg.experts_per_tok)       # (T,K)
+    gate = jax.nn.softmax(top, axis=-1)
+    # each held expert's gate per token; routes to experts held elsewhere
+    # fall outside one_hot's range and weigh nothing here
+    held = jax.nn.one_hot(idx - cfg.first_expert, cfg.num_experts,
+                          dtype=jnp.float32)                   # (T,K,n)
+    w = jnp.einsum("tk,tkn->nt", gate, held).astype(x.dtype)
+    g = jnp.einsum("td,ndf->ntf", xt, p["w_gate"])
+    u = jnp.einsum("td,ndf->ntf", xt, p["w_up"])
+    out = jnp.einsum("ntf,nfd->td", silu(g) * u * w[:, :, None], p["w_down"])
+    if cfg.shared_expert_d_ff:
+        out = out + shared_expert(p["shared"], xt, cfg)
+    return out.reshape(B, S, d), _aux_loss(logits, idx[:, 0], cfg)
 
 
 def _capacity(tokens: int, cfg) -> int:
@@ -147,13 +202,8 @@ def moe_ffn_shardmap(p, x, cfg, ctx):
     aux = cfg.router_aux_coef * E * jnp.sum(me * ce)
 
     if cfg.shared_expert_d_ff:
-        sp = p["shared"]
-        xt = x.reshape(-1, d)
-        sgate = jax.nn.sigmoid(jnp.einsum("td,do->to", xt, sp["gate"]
-                                          ).astype(jnp.float32))
-        out = out + (sgate.astype(x.dtype) *
-                     swiglu(xt, sp["w_gate"], sp["w_up"], sp["w_down"])
-                     ).reshape(B, S, d)
+        out = out + shared_expert(p["shared"], x.reshape(-1, d),
+                                  cfg).reshape(B, S, d)
     return out, aux
 
 
@@ -162,6 +212,8 @@ def moe_ffn(p, x, cfg, ctx=None):
     if (ctx is not None and ctx.extra.get("moe_impl") == "shardmap"
             and ctx.rules.get("expert_mode") in ("ep", "tp")):
         return moe_ffn_shardmap(p, x, cfg, ctx)
+    if cfg.dropless:
+        return moe_dropless(p, x, cfg)
     B, S, d = x.shape
     T = B * S
     E, K = cfg.num_experts, cfg.experts_per_tok
@@ -174,10 +226,7 @@ def moe_ffn(p, x, cfg, ctx=None):
     if cfg.norm_topk_prob:
         gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
 
-    # Load-balance auxiliary loss (Switch-style).
-    me = jnp.mean(probs, axis=0)
-    ce = jnp.mean(jax.nn.one_hot(idx[:, 0], E, dtype=jnp.float32), axis=0)
-    aux = cfg.router_aux_coef * E * jnp.sum(me * ce)
+    aux = _aux_loss(logits, idx[:, 0], cfg)
 
     # ---- dispatch: rank each (token, slot) within its expert via sort ----
     e_flat = idx.reshape(-1)                                  # (T*K,)
@@ -212,8 +261,5 @@ def moe_ffn(p, x, cfg, ctx=None):
         out = out + eo_flat[slot[:, k]] * w[:, k:k + 1]
 
     if cfg.shared_expert_d_ff:
-        sp = p["shared"]
-        sgate = jax.nn.sigmoid(jnp.einsum("td,do->to", xt, sp["gate"]).astype(jnp.float32))
-        out = out + (sgate.astype(x.dtype) *
-                     swiglu(xt, sp["w_gate"], sp["w_up"], sp["w_down"]))
+        out = out + shared_expert(p["shared"], xt, cfg)
     return out.reshape(B, S, d), aux

@@ -27,6 +27,8 @@ def ssm_spec(cfg):
         "w_C": P((d, N), ("embed", "ssm_state")),
         "w_dt": P((d, H), ("embed", "ssm_heads")),
         "conv_w": P((din + 2 * N, W), ("conv", None)),
+        **({"conv_b": P((din + 2 * N,), ("conv",), scale=0.02)}
+           if cfg.conv_bias else {}),
         "dt_bias": P((H,), ("ssm_heads",), init="dt_bias"),
         "A_log": P((H,), ("ssm_heads",), init="a_log"),
         "D": P((H,), ("ssm_heads",), init="ones"),
@@ -119,7 +121,8 @@ def ssm_forward(p, x_res, cfg, ctx=None, conv_state=None, ssm_state=None):
     dt_raw = jnp.einsum("bsd,dh->bsh", x_res, p["w_dt"])
 
     conv_in = jnp.concatenate([xb, Bv, Cv], axis=-1)
-    conv_out, new_conv = causal_conv1d(conv_in, p["conv_w"], conv_state)
+    conv_out, new_conv = causal_conv1d(conv_in, p["conv_w"], conv_state,
+                                       bias=p.get("conv_b"))
     xb, Bv, Cv = jnp.split(conv_out, [din, din + N], axis=-1)
 
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32) +
@@ -149,7 +152,8 @@ def ssm_decode_step(p, x_res, cfg, conv_state, ssm_state, ctx=None):
     dt_raw = jnp.einsum("bsd,dh->bsh", x_res, p["w_dt"])
 
     conv_in = jnp.concatenate([xb, Bv, Cv], axis=-1)
-    conv_out, new_conv = causal_conv1d(conv_in, p["conv_w"], conv_state)
+    conv_out, new_conv = causal_conv1d(conv_in, p["conv_w"], conv_state,
+                                       bias=p.get("conv_b"))
     xb, Bv, Cv = jnp.split(conv_out, [din, din + N], axis=-1)
 
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + p["dt_bias"].astype(jnp.float32))

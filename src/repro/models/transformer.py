@@ -35,17 +35,23 @@ def mlp_spec(cfg):
     }
 
 
+def ffn_spec(cfg):
+    return moe.moe_spec(cfg) if cfg.num_experts else mlp_spec(cfg)
+
+
 def layer_spec(cfg, kind: str):
     d = cfg.d_model
     ln = lambda: P((d,), ("embed",), init="zeros")
     if kind == "ssm":
-        return {"ln": ln(), "mixer": ssm.ssm_spec(cfg)}
+        spec = {"ln": ln(), "mixer": ssm.ssm_spec(cfg)}
+        if cfg.d_ff:   # granite: the mixer is followed by an FFN
+            spec.update(ln2=ln(), ffn=ffn_spec(cfg))
+        return spec
     if kind == "rec":
         return {"ln1": ln(), "mixer": griffin.rglru_spec(cfg),
                 "ln2": ln(), "mlp": mlp_spec(cfg)}
-    spec = {"ln1": ln(), "attn": attn.attn_spec(cfg), "ln2": ln()}
-    spec["ffn"] = moe.moe_spec(cfg) if cfg.family == "moe" else mlp_spec(cfg)
-    return spec
+    return {"ln1": ln(), "attn": attn.attn_spec(cfg), "ln2": ln(),
+            "ffn": ffn_spec(cfg)}
 
 
 def decoder_spec(cfg):
@@ -71,6 +77,24 @@ def _o_proj(o, wo):
     return jnp.einsum("bshk,hkd->bsd", o, wo)
 
 
+def _residual(x, y, cfg):
+    """``x`` plus the branch ``y``, scaled by the residual multiplier."""
+    m = cfg.residual_multiplier
+    return x + (y if m == 1.0 else y * m)
+
+
+def _ffn_block(lp, x, cfg, ctx):
+    """Pre-norm FFN on the residual stream: the MoE (with its shared
+    expert) or a dense SwiGLU.  Returns (x, aux)."""
+    h = rms_norm(x, lp["ln2"], cfg.norm_eps)
+    if cfg.num_experts:
+        f, aux = moe.moe_ffn(lp["ffn"], h, cfg, ctx)
+    else:
+        f, aux = swiglu(h, lp["ffn"]["w_gate"], lp["ffn"]["w_up"],
+                        lp["ffn"]["w_down"]), 0.0
+    return _residual(x, f, cfg), aux
+
+
 def attn_block_fwd(lp, x, cfg, ctx, positions, *, window: int = 0,
                    want_cache: bool = False, cache_len: int | None = None):
     """Returns (x, aux, cache_entry)."""
@@ -81,17 +105,12 @@ def attn_block_fwd(lp, x, cfg, ctx, positions, *, window: int = 0,
         o = attn.banded_local_attention(q, k, v, window=window)
     else:
         cq = S if cfg.exact_costs else 1024
-        o = attn.full_causal_attention(q, k, v, chunk_q=cq)
-    x = x + _o_proj(o, lp["attn"]["wo"])
+        o = attn.full_causal_attention(q, k, v, chunk_q=cq,
+                                       scale=cfg.attention_multiplier)
+    x = _residual(x, _o_proj(o, lp["attn"]["wo"]), cfg)
     x = shard(ctx, x, "batch", "seq", None)
 
-    h2 = rms_norm(x, lp["ln2"], cfg.norm_eps)
-    if cfg.family == "moe":
-        f, aux = moe.moe_ffn(lp["ffn"], h2, cfg, ctx)
-    else:
-        f, aux = swiglu(h2, lp["ffn"]["w_gate"], lp["ffn"]["w_up"],
-                        lp["ffn"]["w_down"]), 0.0
-    x = x + f
+    x, aux = _ffn_block(lp, x, cfg, ctx)
     x = shard(ctx, x, "batch", "seq", None)
 
     entry = None
@@ -114,10 +133,14 @@ def attn_block_fwd(lp, x, cfg, ctx, positions, *, window: int = 0,
 def ssm_block_fwd(lp, x, cfg, ctx, *, want_cache: bool = False):
     h = rms_norm(x, lp["ln"], cfg.norm_eps)
     y, (conv_st, ssm_st) = ssm.ssm_forward(lp["mixer"], h, cfg, ctx)
-    x = x + y
+    x = _residual(x, y, cfg)
     x = shard(ctx, x, "batch", "seq", None)
+    aux = 0.0
+    if "ffn" in lp:
+        x, aux = _ffn_block(lp, x, cfg, ctx)
+        x = shard(ctx, x, "batch", "seq", None)
     entry = {"conv": conv_st, "ssm": ssm_st} if want_cache else None
-    return x, 0.0, entry
+    return x, aux, entry
 
 
 def rec_block_fwd(lp, x, cfg, ctx, *, want_cache: bool = False):
@@ -157,22 +180,21 @@ def attn_block_dec(lp, x, cfg, ctx, pos, cache, *, window: int = 0):
         o, kc, vc = sp_decode_attention(ctx, q, cache["k"], cache["v"], k, v, pos)
     else:
         kc, vc = attn.cache_write_plain(cache["k"], cache["v"], k, v, pos)
-        o = attn.decode_attention_plain(q, kc, vc, pos)
-    x = x + _o_proj(o, lp["attn"]["wo"])
-
-    h2 = rms_norm(x, lp["ln2"], cfg.norm_eps)
-    if cfg.family == "moe":
-        f, _ = moe.moe_ffn(lp["ffn"], h2, cfg, ctx)
-    else:
-        f = swiglu(h2, lp["ffn"]["w_gate"], lp["ffn"]["w_up"], lp["ffn"]["w_down"])
-    return x + f, {"k": kc, "v": vc}
+        o = attn.decode_attention_plain(q, kc, vc, pos,
+                                        scale=cfg.attention_multiplier)
+    x = _residual(x, _o_proj(o, lp["attn"]["wo"]), cfg)
+    x, _ = _ffn_block(lp, x, cfg, ctx)
+    return x, {"k": kc, "v": vc}
 
 
 def ssm_block_dec(lp, x, cfg, ctx, cache):
     h = rms_norm(x, lp["ln"], cfg.norm_eps)
     y, (conv_st, ssm_st) = ssm.ssm_decode_step(
         lp["mixer"], h, cfg, cache["conv"], cache["ssm"], ctx)
-    return x + y, {"conv": conv_st, "ssm": ssm_st}
+    x = _residual(x, y, cfg)
+    if "ffn" in lp:
+        x, _ = _ffn_block(lp, x, cfg, ctx)
+    return x, {"conv": conv_st, "ssm": ssm_st}
 
 
 def rec_block_dec(lp, x, cfg, ctx, cache):
@@ -347,18 +369,24 @@ def _attn_cache(cfg, L_axis: str, L: int, B: int, S: int, window: int, dtype):
             "v": jnp.zeros((L, B, KV, Sc, hd), dtype)}
 
 
+def _ssm_cache(cfg, L: int, B: int, dtype):
+    C = cfg.d_inner + 2 * cfg.ssm_state
+    return {"conv": jnp.zeros((L, B, cfg.conv_width - 1, C), dtype),
+            "ssm": jnp.zeros((L, B, cfg.ssm_heads, cfg.ssm_headdim,
+                              cfg.ssm_state), dtype)}
+
+
 def init_cache(cfg, B: int, cache_len: int, dtype=jnp.bfloat16):
     """Zeros cache pytree for the decoder stack (call under eval_shape for
-    abstract specs)."""
+    abstract specs).  In a hybrid pattern each slot keeps its own kind:
+    conv and SSM state for ``ssm``, conv and LRU state for ``rec``, a KV
+    cache for attention."""
     kinds = cfg.layer_kinds()
     L = cfg.num_layers
     if len(set(kinds)) == 1:
         kind = kinds[0]
         if kind == "ssm":
-            C = cfg.d_inner + 2 * cfg.ssm_state
-            entry = {"conv": jnp.zeros((L, B, cfg.conv_width - 1, C), dtype),
-                     "ssm": jnp.zeros((L, B, cfg.ssm_heads, cfg.ssm_headdim,
-                                       cfg.ssm_state), dtype)}
+            entry = _ssm_cache(cfg, L, B, dtype)
         else:
             entry = _attn_cache(cfg, "layers", L, B, cache_len, 0, dtype)
         return {"stack": entry, "pos": jnp.zeros((B,), jnp.int32)}
@@ -368,6 +396,8 @@ def init_cache(cfg, B: int, cache_len: int, dtype=jnp.bfloat16):
     gcache, tcache = {}, {}
 
     def one(kind, n):
+        if kind == "ssm":
+            return _ssm_cache(cfg, n, B, dtype)
         if kind == "rec":
             return {"conv": jnp.zeros((n, B, cfg.conv_width - 1, cfg.lru_width), dtype),
                     "lru": jnp.zeros((n, B, cfg.lru_width), dtype)}
@@ -400,7 +430,10 @@ def cache_axes(cfg, ctx) -> Any:
     G = L // len(pat)
 
     def one(kind, stacked=True):
-        if kind == "rec":
+        if kind == "ssm":
+            c = {"conv": ("groups", "batch", None, None),
+                 "ssm": ("groups", "batch", "ssm_heads", None, None)}
+        elif kind == "rec":
             c = {"conv": ("groups", "batch", None, "lru"),
                  "lru": ("groups", "batch", "lru")}
         else:

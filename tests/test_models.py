@@ -43,7 +43,8 @@ def _decode_consistency(arch, **cfg_over):
 
 @pytest.mark.parametrize("arch", ["yi-6b", "stablelm-12b", "mamba2-370m",
                                   "recurrentgemma-9b", "whisper-tiny",
-                                  "internvl2-2b", "qwen3-moe-235b-a22b"])
+                                  "internvl2-2b", "qwen3-moe-235b-a22b",
+                                  "granite-4.0-h-small"])
 def test_decode_matches_forward(arch):
     # MoE needs headroom so capacity drops are identical across paths
     over = {"capacity_factor": 8.0} if "moe" in arch else {}
