@@ -6,7 +6,6 @@ elastic serving infrastructure — DESIGN.md §1).
 """
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.configs import get_config
 from repro.core import ExecutionEnvironment, MigrationEngine, StateReducer
@@ -37,15 +36,14 @@ print(f"migrated serving state: {res.nbytes/1e6:.2f} MB "
       f"(params+cache+cursor) in {res.seconds:.3f}s modeled")
 
 # --- continue decoding on the pod: stream must be seamless ------------
+# device arrays are restored as device arrays, so the pod decodes from them
+# as they arrived
 p_params, p_cache, p_tok = (pod.state["params"], pod.state["cache"],
                             pod.state["last_tok"])
-p_params = jax.tree_util.tree_map(jnp.asarray, p_params)
-p_cache = jax.tree_util.tree_map(jnp.asarray, p_cache)
 cont_pod, cont_edge = [], []
 tok_e = tok
 for _ in range(4):
-    logits_p, p_cache = lm.decode_step(p_params, p_cache,
-                                       {"token": jnp.asarray(p_tok)})
+    logits_p, p_cache = lm.decode_step(p_params, p_cache, {"token": p_tok})
     p_tok = jnp.argmax(logits_p, -1)[:, None].astype(jnp.int32)
     cont_pod.append(int(p_tok[0, 0]))
     logits_e, cache = lm.decode_step(params, cache, {"token": tok_e})

@@ -198,9 +198,15 @@ def _prepare_array(a, codec: str) -> tuple[dict, Any]:
     exists (:func:`_byte_view`): a pulled device array is private to the
     capture, and a host array is the namespace's own, read in place.
 
+    A ``jax.Array`` on one device is marked ``device`` in the meta, so
+    that restore puts it back on a device; a host array's meta has no such
+    key, and a sharded array is restored to the host whole.
+
     Digesting is deferred so the caller can batch every payload of a
     capture into one device launch (:func:`array_chunk_digests_many`)."""
     meta = {"shape": a.shape, "dtype": str(a.dtype)}
+    if isinstance(a, jax.Array) and len(a.devices()) == 1:
+        meta["device"] = True
     if codec == "quant8+zstd" and a.dtype in (np.dtype("float32"),
                                               np.dtype("float64"),
                                               jnp.bfloat16.dtype):
@@ -220,9 +226,17 @@ def _prepare_array(a, codec: str) -> tuple[dict, Any]:
 
 @spans.spanned("reducer.assemble")
 def _decode_array(meta: dict, codec: str, chunks: dict[int, bytes],
-                  store=None) -> np.ndarray:
+                  store=None):
+    """One array of a manifest, from its chunks.  An array captured from a
+    device comes back as a ``jax.Array`` on the default device
+    (``jax.devices()[0]``), where this process can hold its dtype; every
+    other array comes back as a new, writable numpy array."""
     shape = tuple(meta["shape"])
     dtype = np.dtype(meta["dtype"]) if meta["dtype"] != "bfloat16" else jnp.bfloat16.dtype
+    # a dtype this process's jax would narrow (64-bit without x64) stays
+    # on the host, bit for bit
+    on_device = (meta.get("device", False)
+                 and jax.dtypes.canonicalize_dtype(dtype) == dtype)
 
     def fetch(d: int):
         if d in chunks:
@@ -239,7 +253,7 @@ def _decode_array(meta: dict, codec: str, chunks: dict[int, bytes],
         s = np.frombuffer(_decompress(meta["scales"], codec), np.float32)
         x = dequantize(jnp.asarray(q), jnp.asarray(s), shape,
                        jnp.dtype(dtype))
-        return np.asarray(x)
+        return x if on_device else np.asarray(x)
     # one copy: each chunk body lands at its offset in the new array (a
     # body that runs past the end fails the slice assignment)
     out = np.empty(shape, dtype)
@@ -253,7 +267,12 @@ def _decode_array(meta: dict, codec: str, chunks: dict[int, bytes],
         raise ValueError(f"chunks of a {shape} {dtype} array hold {pos} of "
                          f"its {len(dst)} bytes")
     spans.count("copied_bytes", pos)
-    return out
+    if not on_device:
+        return out
+    # the host buffer is private to this restore: nothing writes it while
+    # the transfer runs, and it dies with the last reference to it
+    with spans.span("reducer.place"):
+        return jax.device_put(out, jax.devices()[0])
 
 
 # ----------------------------------------------------------------------
@@ -471,15 +490,22 @@ class StateReducer:
                     target_ns: dict | None = None,
                     chunk_store=None) -> dict[str, Any]:
         """Rebuild objects; chunks resolve from ``ser.chunks`` first, then
-        from ``chunk_store`` (the receiver's CAS)."""
+        from ``chunk_store`` (the receiver's CAS).
+
+        Counts ``placed_bytes``: the bytes of the arrays restored onto a
+        device (:func:`_decode_array`)."""
         token = _TARGET_NS.set(target_ns)
+        placed = 0
         try:
             out: dict[str, Any] = {}
             for name, blob in ser.blobs.items():
                 store = [_decode_array(m, ser.codec, ser.chunks, chunk_store)
                          for m in blob.arrays]
+                placed += sum(a.nbytes for a in store
+                              if isinstance(a, jax.Array))
                 buf = io.BytesIO(_decompress(blob.pickle_bytes, ser.codec))
                 out[name] = _Unpickler(buf, store).load()
+            spans.count("placed_bytes", placed)
             return out
         finally:
             _TARGET_NS.reset(token)

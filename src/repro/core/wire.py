@@ -384,6 +384,8 @@ def manifest_frame(ser, *, deleted: Iterable[str] = (),
             if a["quant"]:
                 meta["block"] = int(a["block"])
                 meta["scales"] = _b64(a["scales"])
+            if a.get("device"):      # host arrays carry no such key
+                meta["device"] = True
             arrays.append(meta)
         blobs[name] = {"pickle": _b64(blob.pickle_bytes), "arrays": arrays}
     doc = {
@@ -416,6 +418,8 @@ def parse_manifest(frame: Frame):
                 if meta["quant"]:
                     meta["block"] = int(a["block"])
                     meta["scales"] = _unb64(a["scales"])
+                if a.get("device"):
+                    meta["device"] = True
                 arrays.append(meta)
             blobs[name] = SerializedName(pickle_bytes=_unb64(b["pickle"]),
                                          arrays=arrays)

@@ -1,6 +1,8 @@
 """One host copy per byte through the chunk store: capture encodes chunks
 from byte views of the array, restore fills a new array in place.  Chunks,
-keys and manifests are the bytes the copying path made."""
+keys and manifests are the bytes the copying path made; an array captured
+from a device is marked so, and comes back on a device."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -34,8 +36,11 @@ LOSSLESS = [c for c in CODECS if c != "quant8+zstd"]
 
 def _old_capture(a, codec):
     """The capture before payloads became views: ``tobytes`` of the host
-    array, then ``bytes`` slices, each encoded."""
+    array, then ``bytes`` slices, each encoded.  A device array's meta
+    says so."""
     meta = {"shape": a.shape, "dtype": str(a.dtype)}
+    if isinstance(a, jax.Array):
+        meta["device"] = True
     if codec == "quant8+zstd" and a.dtype in (np.dtype("float32"),
                                               np.dtype("float64"),
                                               jnp.bfloat16.dtype):
@@ -91,8 +96,13 @@ def test_restored_array_is_bit_equal_writable_and_its_own(kind, codec,
         store.put_many(ser.chunks)
         ser.chunks = {}
     out = red.deserialize(ser, chunk_store=store)["x"]
-    assert isinstance(out, np.ndarray)
     assert _bits(out) == _bits(a)
+    if isinstance(a, jax.Array):
+        # a device array comes back on the default device
+        assert isinstance(out, jax.Array)
+        assert out.devices() == {jax.devices()[0]}
+        return
+    assert isinstance(out, np.ndarray)
     assert out.flags.writeable and out.flags.owndata
     assert not any(np.shares_memory(out, np.frombuffer(c, np.uint8))
                    for c in held)
