@@ -36,8 +36,6 @@ MODULES = [
     "bench_gateway",              # persistent gateway: 10k-session storm
     "bench_replica",              # replica plane: failover promotion / racing
     "bench_cost",                 # cost plane: dollars DP / spot / data gravity
-    "kernel_bench",               # kernels
-    "roofline_dump",              # §Roofline table feed
 ]
 
 # module -> the JSON artifact it (re)writes; used for stale-artifact removal

@@ -1,6 +1,6 @@
 """Serving + live state migration: a serving session whose model weights and
 KV cache migrate between environments mid-stream (the paper's migration as
-elastic serving infrastructure — DESIGN.md §1).
+elastic serving infrastructure).
 
     PYTHONPATH=src python examples/serve_migrate.py
 """

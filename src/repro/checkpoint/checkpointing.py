@@ -1,4 +1,4 @@
-"""Checkpointing *is* migration to a storage environment (DESIGN.md §1).
+"""Checkpointing *is* migration to a storage environment.
 
 A checkpoint directory is a ``kind="storage"`` :class:`ExecutionEnvironment`
 backed by an on-disk content-addressed chunk store.  ``save`` flattens the

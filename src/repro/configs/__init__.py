@@ -1,20 +1,15 @@
 from repro.configs.base import (
     ModelConfig,
-    RuntimeConfig,
     ShapeConfig,
     TrainConfig,
 )
 from repro.configs.registry import ASSIGNED_ARCHS, all_configs, get_config
-from repro.configs.shapes import SHAPES, shape_applicable
 
 __all__ = [
     "ModelConfig",
-    "RuntimeConfig",
     "ShapeConfig",
     "TrainConfig",
     "ASSIGNED_ARCHS",
     "all_configs",
     "get_config",
-    "SHAPES",
-    "shape_applicable",
 ]

@@ -1,13 +1,11 @@
-"""Configuration dataclasses for models, shapes, training and runtime.
+"""Configuration dataclasses for models, shapes and training.
 
-Every assigned architecture is expressed as a :class:`ModelConfig`; the four
-assigned input-shape suites are :class:`ShapeConfig`.  FULL configs are only
-ever lowered abstractly (ShapeDtypeStruct) by the dry-run; smoke tests use the
-``reduced()`` variants.
+Every assigned architecture is expressed as a :class:`ModelConfig`; an input
+shape (sequence length and batch) is a :class:`ShapeConfig`.  Smoke tests use
+the ``reduced()`` variants.
 """
 from __future__ import annotations
 
-import dataclasses
 from dataclasses import dataclass, replace
 from typing import Any
 
@@ -274,19 +272,3 @@ class TrainConfig:
     microbatches: int = 1            # gradient accumulation
     vocab_parallel: bool = False     # Megatron-style shard_map embed/loss
     seed: int = 0
-
-
-@dataclass(frozen=True)
-class RuntimeConfig:
-    """Migration-runtime knobs (the paper's tool)."""
-    compression: str = "zlib"        # none | zlib | zstd | quant8+zstd
-    delta_migration: bool = True
-    reduce_state: bool = True
-    block_policy: bool = True        # block-cell (vs single-cell) migration
-    knowledge_policy: bool = True
-    migration_bandwidth: float = 1e9   # bytes/s (local<->remote link)
-    migration_latency: float = 0.5     # seconds fixed per migration
-
-
-def asdict(cfg) -> dict:
-    return dataclasses.asdict(cfg)

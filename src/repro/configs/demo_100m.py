@@ -1,8 +1,8 @@
-"""demo-100m — ~100M-parameter llama-like LM for the end-to-end examples.
+"""demo-100m — ~100M-parameter llama-like LM for the migration tests.
 
-Not an assigned architecture; used by examples/train_e2e.py and the hybrid
-migration examples (the paper's own workloads are notebook pipelines, so this
-plays the role of its "model fitting" cell at a size that trains on CPU).
+Not an assigned architecture; used by the hybrid migration tests (the
+paper's own workloads are notebook pipelines, so this plays the role of its
+"model fitting" cell at a size that trains on CPU).
 """
 from repro.configs.base import ModelConfig
 

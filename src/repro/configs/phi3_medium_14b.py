@@ -2,7 +2,7 @@
 
 40L d_model=5120 40H (GQA kv=10) d_ff=17920 vocab=100352.
 kv=10 is not divisible by the 16-way model axis -> KV replicated under TP
-(DESIGN.md §3), decode cache sequence-sharded instead.
+(``distributed/sharding.py``), decode cache sequence-sharded instead.
 """
 from repro.configs.base import ModelConfig
 

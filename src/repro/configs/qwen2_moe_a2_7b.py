@@ -1,7 +1,7 @@
 """qwen2-moe-a2.7b — 4 shared + 60 routed experts, top-4 [hf:Qwen/Qwen1.5-MoE-A2.7B].
 
 24L d_model=2048 16H (kv=16) routed-expert d_ff=1408, shared expert 5632,
-vocab=151936.  60 % 16 != 0 -> expert-TP sharding mode (DESIGN.md §3).
+vocab=151936.  60 % 16 != 0 -> expert-TP sharding mode (``models/moe.py``).
 """
 from repro.configs.base import ModelConfig
 

@@ -4,8 +4,7 @@ Backbone only: 4 decoder layers + 4 encoder layers, d_model=384 6H (kv=6)
 d_ff=1536 vocab=51865.  The conv frontend is a STUB: ``input_specs()``
 supplies precomputed frame embeddings (batch, 1500, d_model).  Learned
 absolute positions (whisper convention), no RoPE.  Decoder sequence lengths
-beyond whisper's native 448 are a stress configuration mandated by the
-assigned shape suites (DESIGN.md §5).
+beyond whisper's native 448 are a stress configuration.
 """
 from repro.configs.base import ModelConfig
 

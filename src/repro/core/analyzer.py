@@ -318,9 +318,11 @@ class HorizonPolicy(PlacementPolicy):
     not just the best response to one predicted path.  Requires a registry
     (per-pair links + env speedups).
 
-    Cost plane (``analyzer.objective == "dollars"``): the same DP runs over
-    lexicographic ``(expected dollars, expected seconds)`` step costs.
-    Execution on env *e* is priced at ``price_per_hour(e)``; migration legs
+    The DP runs over lexicographic ``(expected dollars, expected seconds)``
+    step costs.  Under ``analyzer.objective == "seconds"`` (the paper's)
+    every dollar term, the hazard surcharge and the SLO are 0, so only the
+    seconds decide.  Under ``"dollars"`` (the cost plane), execution on
+    env *e* is priced at ``price_per_hour(e)``; migration legs
     additionally pay the link's per-GB egress; a spot env's step cost is
     surcharged by its hazard-weighted expected recovery cost (``hazard *
     exec_seconds`` expected preemptions, each priced from the fleet's
@@ -377,6 +379,22 @@ class HorizonPolicy(PlacementPolicy):
             dists.append(d)
         return dists
 
+    # an env whose expected per-cell latency blows the SLO gets this added
+    # to its step dollars: any SLO-feasible route beats it, but if *every*
+    # env is infeasible the DP still produces a well-defined argmin
+    SLO_PENALTY = 1e15
+
+    @staticmethod
+    def _lex_better(cand: tuple[float, float],
+                    best: tuple[float, float]) -> bool:
+        """Lexicographic (dollars, seconds), each compared with a 1e-12
+        epsilon: with every dollar term 0 this is the seconds comparison."""
+        if cand[0] < best[0] - 1e-12:
+            return True
+        if cand[0] > best[0] + 1e-12:
+            return False
+        return cand[1] < best[1] - 1e-12
+
     def decide(self, an, nb, cell, current_env):
         assert an.registry is not None, "horizon policy needs a registry"
         order = nb.order(cell.cell_id)
@@ -384,58 +402,87 @@ class HorizonPolicy(PlacementPolicy):
         cache: dict | None = {} if self.memoize else None
         dists = self._step_distributions(an, nb, order, cache)
         envs = [an.home] + an.candidates()
+        # the seconds objective prices nothing: every dollar term, the
+        # hazard surcharge and the SLO are 0, so the DP runs on seconds
+        priced = an.objective == "dollars"
+        exec_dollars = an.exec_dollars if priced else _no_dollars
+        move_dollars = an.transfer_dollars if priced else _no_dollars
 
-        # expected exec cost per (step, env); a cell missing an estimate on
-        # ANY env contributes to none, keeping the comparison paired like
-        # BlockPolicy (else the only env with evidence would be penalized)
+        # expected dollars and hazard-adjusted seconds per (step, env); a
+        # cell missing an estimate on ANY env contributes to none, keeping
+        # the comparison paired like BlockPolicy (else the only env with
+        # evidence would be penalized)
         known_any = False
-        expected: list[dict[str, float]] = []
+        dol: list[dict[str, float]] = []
+        sec: list[dict[str, float]] = []
         for d in dists:
-            row: dict[str, float] = {e: 0.0 for e in envs}
+            drow = {e: 0.0 for e in envs}
+            srow = {e: 0.0 for e in envs}
             for c_order, p in d.items():
                 ts = {e: _modeled_exec_seconds(an, nb.cells[c_order], e)
                       for e in envs}
                 if any(t is None for t in ts.values()):
                     continue
                 for e, t in ts.items():
-                    row[e] += p * t
+                    hs, hd = (an.hazard_surcharge(e, t, state) if priced
+                              else (0.0, 0.0))
+                    drow[e] += p * (exec_dollars(t, e) + hd)
+                    srow[e] += p * (t + hs)
                 known_any = True
-            expected.append(row)
+            dol.append(drow)
+            sec.append(srow)
         if not known_any:
             return Decision(an.home, False,
                             "horizon: no history or declared costs yet",
                             policy="horizon")
 
-        if an.objective == "dollars":
-            # the price-aware DP lives on its own path so the seconds-only
-            # code below stays float-for-float identical to the seed
-            return self._decide_dollars(an, nb, current_env, order, state,
-                                        dists, envs, cache)
+        # SLO feasibility: worst expected per-cell latency over the horizon
+        # (exec + hazard-weighted recovery).  Entry migration and fleet
+        # overhead are priced in the objective, not the feasibility test —
+        # they hit only the first cell of a block.
+        feasible = {e: True for e in envs}
+        if priced and an.slo is not None:
+            for e in envs:
+                lat = max((s[e] for s in sec), default=0.0)
+                feasible[e] = lat <= an.slo + 1e-12
 
         # backward DP + argmin successor per (step, env); the terminal V is
         # the amortized return-home transfer
-        V = {e: an.pair_migration_time(state, e, an.home) for e in envs}
+        V = {e: (move_dollars(state, e, an.home),
+                 an.pair_migration_time(state, e, an.home)) for e in envs}
         succ: list[dict[str, str]] = []
         for t in range(len(dists) - 1, -1, -1):
-            nv: dict[str, float] = {}
+            nv: dict[str, tuple[float, float]] = {}
             ns: dict[str, str] = {}
             for e in envs:
                 best_e, best_c = None, None
                 for e2 in envs:
-                    c = an.pair_migration_time(state, e, e2) + V[e2]
-                    if best_c is None or c < best_c - 1e-12:
+                    c = (move_dollars(state, e, e2) + V[e2][0],
+                         an.pair_migration_time(state, e, e2) + V[e2][1])
+                    if best_c is None or self._lex_better(c, best_c):
                         best_e, best_c = e2, c
-                nv[e] = expected[t][e] + best_c
+                pen = 0.0 if feasible[e] else self.SLO_PENALTY
+                nv[e] = (dol[t][e] + pen + best_c[0], sec[t][e] + best_c[1])
                 ns[e] = best_e
             succ.append(ns)
             V = nv
         succ.reverse()
 
-        costs = {e: an.pair_migration_time(state, current_env, e) + V[e]
-                 + an.env_overhead(e)
-                 for e in envs}
-        best = min(costs, key=lambda e: (costs[e], e != an.home))
-        matrix = ", ".join(f"{e}={t:.2f}s" for e, t in costs.items())
+        costs = {}
+        for e in envs:
+            over = an.env_overhead(e)
+            pen = 0.0 if feasible[e] else self.SLO_PENALTY
+            costs[e] = (move_dollars(state, current_env, e) + V[e][0]
+                        + exec_dollars(over, e) + pen,
+                        an.pair_migration_time(state, current_env, e)
+                        + V[e][1] + over)
+        best = min(costs, key=lambda e: (costs[e][0], costs[e][1],
+                                         e != an.home))
+        slo_note = ""
+        if costs[best][0] >= self.SLO_PENALTY:
+            # every env blows the SLO: fall back to fastest-expected-seconds
+            best = min(costs, key=lambda e: (costs[e][1], e != an.home))
+            slo_note = f"; SLO {an.slo:.1f}s unattainable, fastest env chosen"
 
         # block plan: the greedy most-likely cell path for as long as the
         # DP keeps the placement on the chosen env
@@ -456,136 +503,25 @@ class HorizonPolicy(PlacementPolicy):
                     break
                 block.append(c)
 
-        if best == current_env:
-            return Decision(best, False,
-                            f"horizon(H={len(dists)}): stay on {best} "
-                            f"[{matrix}]",
-                            block=tuple(block) if best != an.home else (),
-                            policy="horizon")
-        return Decision(best, True,
-                        f"horizon(H={len(dists)}): {best} minimizes expected "
-                        f"cost [{matrix}]",
+        if priced:
+            tag, goal = "horizon-$", "expected dollars"
+            matrix = ", ".join(
+                f"{e}=${costs[e][0] % self.SLO_PENALTY:.4f}/{costs[e][1]:.2f}s"
+                + ("" if feasible[e] else "!slo") for e in envs)
+        else:
+            tag, goal = "horizon", "expected cost"
+            matrix = ", ".join(f"{e}={costs[e][1]:.2f}s" for e in envs)
+        move = best != current_env
+        what = f"{best} minimizes {goal}" if move else f"stay on {best}"
+        return Decision(best, move,
+                        f"{tag}(H={len(dists)}): {what} [{matrix}]{slo_note}",
                         block=tuple(block) if best != an.home else (),
                         policy="horizon")
 
-    # -- price-aware DP (cost plane) -------------------------------------
-    # an env whose expected per-cell latency blows the SLO gets this added
-    # to its step dollars: any SLO-feasible route beats it, but if *every*
-    # env is infeasible the DP still produces a well-defined argmin
-    SLO_PENALTY = 1e15
 
-    @staticmethod
-    def _lex_better(cand: tuple[float, float],
-                    best: tuple[float, float]) -> bool:
-        """Lexicographic (dollars, seconds) with the same 1e-12 epsilon the
-        seconds DP uses — so an all-prices-zero fleet reproduces the
-        seconds DP's successor choices exactly."""
-        if cand[0] < best[0] - 1e-12:
-            return True
-        if cand[0] > best[0] + 1e-12:
-            return False
-        return cand[1] < best[1] - 1e-12
-
-    def _decide_dollars(self, an, nb, current_env, order, state, dists,
-                        envs, cache):
-        """Backward DP over (step, env) minimizing lexicographic
-        (expected dollars, expected seconds) subject to the per-cell SLO."""
-        # per-(step, env) expected dollars + hazard-adjusted seconds; the
-        # pairing rule matches the seconds path: a cell missing an estimate
-        # on any env contributes to none
-        dol: list[dict[str, float]] = []
-        sec: list[dict[str, float]] = []
-        for d in dists:
-            drow = {e: 0.0 for e in envs}
-            srow = {e: 0.0 for e in envs}
-            for c_order, p in d.items():
-                ts = {e: _modeled_exec_seconds(an, nb.cells[c_order], e)
-                      for e in envs}
-                if any(t is None for t in ts.values()):
-                    continue
-                for e, t in ts.items():
-                    hs, hd = an.hazard_surcharge(e, t, state)
-                    drow[e] += p * (an.exec_dollars(t, e) + hd)
-                    srow[e] += p * (t + hs)
-            dol.append(drow)
-            sec.append(srow)
-
-        # SLO feasibility: worst expected per-cell latency over the horizon
-        # (exec + hazard-weighted recovery).  Entry migration and fleet
-        # overhead are priced in the objective, not the feasibility test —
-        # they hit only the first cell of a block.
-        feasible = {e: True for e in envs}
-        if an.slo is not None:
-            for e in envs:
-                lat = max((s[e] for s in sec), default=0.0)
-                feasible[e] = lat <= an.slo + 1e-12
-
-        V = {e: (an.transfer_dollars(state, e, an.home),
-                 an.pair_migration_time(state, e, an.home)) for e in envs}
-        succ: list[dict[str, str]] = []
-        for t in range(len(dists) - 1, -1, -1):
-            nv: dict[str, tuple[float, float]] = {}
-            ns: dict[str, str] = {}
-            for e in envs:
-                best_e, best_c = None, None
-                for e2 in envs:
-                    c = (an.transfer_dollars(state, e, e2) + V[e2][0],
-                         an.pair_migration_time(state, e, e2) + V[e2][1])
-                    if best_c is None or self._lex_better(c, best_c):
-                        best_e, best_c = e2, c
-                pen = 0.0 if feasible[e] else self.SLO_PENALTY
-                nv[e] = (dol[t][e] + pen + best_c[0], sec[t][e] + best_c[1])
-                ns[e] = best_e
-            succ.append(ns)
-            V = nv
-        succ.reverse()
-
-        costs = {}
-        for e in envs:
-            over = an.env_overhead(e)
-            pen = 0.0 if feasible[e] else self.SLO_PENALTY
-            costs[e] = (an.transfer_dollars(state, current_env, e) + V[e][0]
-                        + an.exec_dollars(over, e) + pen,
-                        an.pair_migration_time(state, current_env, e)
-                        + V[e][1] + over)
-        best = min(costs, key=lambda e: (costs[e][0], costs[e][1],
-                                         e != an.home))
-        slo_note = ""
-        if costs[best][0] >= self.SLO_PENALTY:
-            # every env blows the SLO: fall back to fastest-expected-seconds
-            best = min(costs, key=lambda e: (costs[e][1], e != an.home))
-            slo_note = f"; SLO {an.slo:.1f}s unattainable, fastest env chosen"
-        matrix = ", ".join(
-            f"{e}=${costs[e][0] % self.SLO_PENALTY:.4f}/{costs[e][1]:.2f}s"
-            + ("" if feasible[e] else "!slo") for e in envs)
-
-        block = [order]
-        if best != an.home:
-            e, c = best, order
-            for t in range(1, len(dists)):
-                e = succ[t - 1][e]
-                if e != best:
-                    break
-                step = {c2: p for c2, p in self._dist(an, nb, c, cache).items()
-                        if 0 <= c2 < len(nb.cells)}
-                if not step:
-                    break
-                c = max(step.items(), key=lambda kv: (kv[1], -kv[0]))[0]
-                if c in block or c < block[-1]:
-                    break
-                block.append(c)
-
-        if best == current_env:
-            return Decision(best, False,
-                            f"horizon-$(H={len(dists)}): stay on {best} "
-                            f"[{matrix}]{slo_note}",
-                            block=tuple(block) if best != an.home else (),
-                            policy="horizon")
-        return Decision(best, True,
-                        f"horizon-$(H={len(dists)}): {best} minimizes "
-                        f"expected dollars [{matrix}]{slo_note}",
-                        block=tuple(block) if best != an.home else (),
-                        policy="horizon")
+def _no_dollars(*_args) -> float:
+    """The dollar price of anything under the seconds objective."""
+    return 0.0
 
 
 POLICIES = {"single": SingleCellPolicy, "block": BlockPolicy,

@@ -2,8 +2,8 @@
 serialization onto a content-addressed store, content hashing, delta
 migration, compression codecs.
 
-Pipeline (faithful to the paper, TPU-adapted per DESIGN.md §4, then
-generalized from name to chunk granularity):
+Pipeline (faithful to the paper, with digests and codecs as TPU kernels,
+then generalized from name to chunk granularity):
 
 1. ``reduce``: AST Load-closure over the live namespace -> needed names only.
 2. ``serialize``: arrays leave the pickle stream and their raw buffers are

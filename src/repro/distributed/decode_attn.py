@@ -1,7 +1,7 @@
 """Sequence-parallel decode attention (shard_map over the "model" axis).
 
-Decode KV caches are sharded along the *sequence* axis over "model"
-(DESIGN.md §3): each shard holds S/m cache slots, computes a partial
+Decode KV caches are sharded along the *sequence* axis over "model":
+each shard holds S/m cache slots, computes a partial
 flash-style (m, l, o) against its slice, and the partials merge with a
 log-sum-exp psum.  This is what lets 32k x 128-batch caches fit v5e HBM
 (e.g. qwen3-moe: 806 GB global -> 1.6 GB/chip) without replicating KV heads.

@@ -12,7 +12,7 @@ instead of RPC) is simulated:
   persistent stragglers are evicted exactly like failures (re-dispatched),
   transient ones are absorbed by the synchronous barrier.
 * Elastic rescale is a state migration: params/opt state move through the
-  MigrationEngine with a new target sharding (DESIGN.md §1 mapping).
+  MigrationEngine with a new target sharding.
 """
 from __future__ import annotations
 

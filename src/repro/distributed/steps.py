@@ -2,7 +2,7 @@
 
 ``build_train_step`` / ``build_prefill_step`` / ``build_decode_step`` return
 (jitted_fn, in_shardings, out_shardings, abstract_inputs) ready for
-``.lower(...).compile()`` — the dry-run consumes exactly this.
+``.lower(...).compile()``.
 """
 from __future__ import annotations
 

@@ -1,6 +1,6 @@
 """RG-LRU linear recurrence as a Pallas TPU kernel.
 
-TPU adaptation (DESIGN.md §4): Griffin's GPU kernel is a warp-parallel scan;
+TPU adaptation: Griffin's GPU kernel is a warp-parallel scan;
 on TPU we run the recurrence sequentially over sequence blocks — grid
 (B, W/bw, S/bs) with the sequence axis innermost — carrying the (1, bw)
 hidden state in VMEM scratch.  Inside a block the recurrence over ``bs``

@@ -1,6 +1,6 @@
 """Mamba-2 SSD chunk scan as a Pallas TPU kernel.
 
-TPU adaptation of the SSD algorithm (DESIGN.md §4): the GPU implementation
+TPU adaptation of the SSD algorithm: the GPU implementation
 leans on warp-level parallel scans; on TPU we exploit the *sequential* grid
 traversal instead — grid (B, H, num_chunks) with chunks innermost, carrying
 the (P, N) inter-chunk state in VMEM scratch, so the whole scan is one

@@ -1,11 +1,7 @@
-"""Batched serving driver: prefill a prompt batch, decode N tokens.
+"""Serving driver for notebook sessions, in one of two modes.
 
-    PYTHONPATH=src python -m repro.launch.serve --arch yi-6b --reduced \
-        --batch 4 --prompt-len 48 --gen 16
-
-Notebook-fleet mode serves many concurrent notebook *sessions* instead of
-token batches — the migration subsystem's serving story: N users' sessions
-multiplexed by the SessionScheduler over a shared accelerator fabric.
+Notebook-fleet mode serves N users' sessions multiplexed by the
+SessionScheduler over a shared accelerator fabric:
 
     PYTHONPATH=src python -m repro.launch.serve --notebook-fleet 8 \
         [--fleet-gpu-capacity 2] [--fleet-tpu-capacity 1]
@@ -30,7 +26,6 @@ from __future__ import annotations
 
 import argparse
 import json
-import time
 
 
 def parse_tenant_spec(spec: str) -> list[tuple[str, float, int | None]]:
@@ -201,15 +196,10 @@ def main():
     from repro.launch.cache import use_compile_cache
     use_compile_cache()
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="demo-100m")
-    ap.add_argument("--reduced", action="store_true")
-    ap.add_argument("--batch", type=int, default=4)
-    ap.add_argument("--prompt-len", type=int, default=48)
-    ap.add_argument("--gen", type=int, default=16)
-    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seed of the gateway's attach storms")
     ap.add_argument("--notebook-fleet", type=int, default=0,
-                    help="serve N concurrent notebook sessions instead of "
-                         "an LM token batch")
+                    help="serve N concurrent notebook sessions")
     ap.add_argument("--fleet-gpu-capacity", type=int, default=2)
     ap.add_argument("--fleet-tpu-capacity", type=int, default=1)
     ap.add_argument("--gateway", type=int, default=None, metavar="N",
@@ -280,57 +270,13 @@ def main():
         print("ok")
         return
 
-    if args.notebook_fleet:
-        report = serve_notebook_fleet(
-            args.notebook_fleet, gpu_capacity=args.fleet_gpu_capacity,
-            tpu_capacity=args.fleet_tpu_capacity,
-            replicas=args.replicas, race=args.race == "on")
-        print(json.dumps(report, indent=2))
-        print("ok")
-        return
-
-    import jax
-    import jax.numpy as jnp
-
-    from repro.configs import get_config
-    from repro.configs.base import ShapeConfig
-    from repro.data import TokenPipeline
-    from repro.models import LM
-
-    cfg = get_config(args.arch, reduced=args.reduced)
-    total = args.prompt_len + args.gen
-    lm = LM(cfg, max_seq=total)
-    shape = ShapeConfig("cli", "prefill", args.prompt_len, args.batch)
-    pipe = TokenPipeline(cfg, shape, seed=args.seed)
-
-    hb = pipe.prefill_batch(0)
-    batch = {k: jnp.asarray(v) for k, v in hb.items()}
-
-    prefill = jax.jit(lambda p, b: lm.prefill(p, b, cache_len=total))
-    decode = jax.jit(lm.decode_step)
-
-    params = lm.init(jax.random.PRNGKey(args.seed))
-    t0 = time.perf_counter()
-    logits, cache = prefill(params, batch)
-    logits.block_until_ready()
-    t_prefill = time.perf_counter() - t0
-
-    toks = []
-    t0 = time.perf_counter()
-    tok = jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
-    for _ in range(args.gen):
-        toks.append(tok)
-        logits, cache = decode(params, cache, {"token": tok})
-        tok = jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
-    jax.block_until_ready(toks[-1])
-    t_decode = time.perf_counter() - t0
-
-    out = jnp.concatenate(toks, axis=1)
-    print(f"prefill {args.batch}x{args.prompt_len}: {t_prefill:.2f}s; "
-          f"decode {args.gen} tokens: {t_decode:.2f}s "
-          f"({args.gen*args.batch/t_decode:.1f} tok/s)")
-    print("sample generated ids:", out[0, :12].tolist())
-    assert bool(jnp.all(out >= 0)) and bool(jnp.all(out < cfg.padded_vocab))
+    if not args.notebook_fleet:
+        ap.error("give a mode: --gateway N or --notebook-fleet N")
+    report = serve_notebook_fleet(
+        args.notebook_fleet, gpu_capacity=args.fleet_gpu_capacity,
+        tpu_capacity=args.fleet_tpu_capacity,
+        replicas=args.replicas, race=args.race == "on")
+    print(json.dumps(report, indent=2))
     print("ok")
 
 

@@ -5,7 +5,7 @@ local window) and decode paths (plain, and sequence-parallel via shard_map in
 All softmax arithmetic is fp32; masks use -1e30 (never -inf) so that empty
 rows stay NaN-free.  A Pallas flash-attention kernel
 (:mod:`repro.kernels.flash_attention`) is selectable with ``impl="pallas"``
-on real TPUs; the XLA paths below are what the CPU dry-run lowers.
+on real TPUs; the XLA paths below run everywhere else.
 """
 from __future__ import annotations
 
@@ -58,8 +58,7 @@ def full_causal_attention(q, k, v, *, chunk_q: int = 1024,
     Scans over query chunks so the (chunk, S) score block is the only
     transient — keeps prefill_32k within HBM without a kernel.  Note: each
     chunk still computes scores against all S keys (masked), i.e. ~2x the
-    causal-ideal FLOPs; the Pallas kernel closes that gap on real TPUs
-    (EXPERIMENTS.md §Perf).
+    causal-ideal FLOPs; the Pallas kernel closes that gap on real TPUs.
     """
     B, S, H, hd = q.shape
     KV = k.shape[2]

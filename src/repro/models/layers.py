@@ -4,8 +4,8 @@ A model is described once as a tree of :class:`P` leaves (shape + logical
 axes + init rule).  From that single source of truth we derive:
 
 - real parameters (``init_params``) for smoke tests / examples,
-- abstract ``ShapeDtypeStruct`` trees (``abstract_params``) for the dry-run
-  (never allocates),
+- abstract ``ShapeDtypeStruct`` trees (``abstract_params``) for lowering
+  the sharded steps without allocating,
 - logical-axis trees (``axes_tree``) feeding the sharding rules.
 """
 from __future__ import annotations

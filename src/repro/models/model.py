@@ -2,7 +2,7 @@
 
     lm = LM(cfg, max_seq=4096)
     params = lm.init(key)                          # real (smoke tests)
-    aparams = lm.abstract()                        # ShapeDtypeStruct (dry-run)
+    aparams = lm.abstract()                        # ShapeDtypeStruct (lowering)
     loss, metrics = lm.loss(params, batch, ctx)
     logits, cache = lm.prefill(params, batch, ctx)
     logits, cache = lm.decode_step(params, cache, batch, ctx)

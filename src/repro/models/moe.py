@@ -1,6 +1,6 @@
 """Mixture-of-Experts FFN with gather-based capacity dispatch.
 
-Design (DESIGN.md §3): instead of GShard one-hot dispatch einsums (whose
+Design: instead of GShard one-hot dispatch einsums (whose
 dispatch matmul is quadratic in tokens) we sort token->expert assignments,
 ``take`` tokens into an (E, C, d) buffer (gather: zero FLOPs), run the grouped
 expert einsum (the only real FLOPs, ~= active-param FLOPs x capacity factor),

@@ -1,6 +1,6 @@
 """Mamba-2 block: SSD (state-space duality) chunked algorithm [arXiv:2405.21060].
 
-XLA reference path (used by train/prefill/dry-run); the Pallas TPU kernel in
+XLA reference path (used by train and prefill); the Pallas TPU kernel in
 :mod:`repro.kernels.ssd_scan` implements the same chunk-sequential algorithm
 with the running state carried in VMEM scratch.
 

@@ -3,7 +3,7 @@ hybrid / vlm), with train, prefill and decode paths and pytree KV/state caches.
 
 Layer parameters are stacked on a leading "layers" (or "groups") axis so the
 HLO stays small regardless of depth (94-layer qwen3 compiles as one scanned
-block) — essential for dry-run compile times and standard MaxText practice.
+block) — it keeps compile times short and is standard MaxText practice.
 """
 from __future__ import annotations
 

@@ -1,5 +1,6 @@
 # NOTE: no XLA_FLAGS here on purpose — smoke tests and benches must see the
-# single real CPU device; only launch/dryrun.py forces 512 host devices.
+# single real CPU device; test_sharding_subprocess.py asks for 8 host
+# devices in its own subprocesses.
 import signal
 
 import jax

@@ -1,6 +1,5 @@
 """Per-arch reduced-config smoke tests: one real train step on CPU with
-shape + finiteness asserts (the FULL configs are exercised only abstractly
-by the dry-run)."""
+shape + finiteness asserts."""
 import jax
 import jax.numpy as jnp
 import pytest

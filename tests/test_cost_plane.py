@@ -2,6 +2,7 @@
 price-aware horizon DP — including the degenerate-case guarantees (zero
 prices must reproduce the seconds-only DP and the committed decision
 goldens bit-for-bit)."""
+import functools
 import json
 import os
 
@@ -158,6 +159,43 @@ def test_zero_price_fleet_matches_seconds_dp_schedule():
     assert rep_d.actual_env_seconds == rep_s.actual_env_seconds
     assert rt_d.exec_env_seconds == rt_s.exec_env_seconds
     assert rep_d.total_dollars == rep_s.total_dollars == 0.0
+
+
+@functools.cache
+def _horizon_decisions(objective, *, price=0.0, egress=0.0, hazard=0.0,
+                       slo=None):
+    """Every decision of two passes over a training notebook on a
+    local + remote fleet (the second pass has interaction history)."""
+    reg = _reg(remote={"speedup": 10.0, "price_per_hour": price,
+                       "hazard_rate": hazard})
+    if egress:
+        reg.set_egress("local", "remote", egress)
+        reg.set_egress("remote", "local", 2 * egress)
+    nb = gpu_training_notebook("prices", steps=3)
+    rt = HybridRuntime(nb, registry=reg, policy="horizon",
+                       use_knowledge=False, objective=objective, slo=slo)
+    out = []
+    for i in list(range(len(nb.cells))) * 2:
+        rt.run_cell(i)
+        d = rt.last_decision
+        out.append((d.env, d.migrate, d.block, d.reason))
+    return tuple(out)
+
+
+_PRICED = {"price": {"price": 3.0}, "egress": {"egress": 40.0},
+           "hazard": {"hazard": 1 / 30}, "slo": {"slo": 10.0}}
+_PRICED["all"] = {k: v for kw in _PRICED.values() for k, v in kw.items()}
+
+
+@pytest.mark.parametrize("case", list(_PRICED))
+def test_seconds_objective_ignores_prices(case):
+    """Under the seconds objective a price, an egress charge, a hazard and
+    an SLO change no decision, though each changes the dollars DP's."""
+    free = _horizon_decisions("seconds")
+    assert any(len(block) > 1 for _e, _m, block, _r in free)
+    assert _horizon_decisions("seconds", **_PRICED[case]) == free
+    assert _horizon_decisions("dollars", **_PRICED[case]) \
+        != _horizon_decisions("dollars")
 
 
 _FIG_SWEEPS = {"fig5_fig6": "fig5_fig6_policy_speedups",

@@ -1,8 +1,7 @@
 """Sharded-execution tests in a subprocess with 8 host devices.
 
 (The main test process must keep the default single device — see conftest.)
-These actually EXECUTE sharded programs, unlike the dry-run which only
-compiles them.
+These actually EXECUTE sharded programs.
 """
 import os
 import subprocess
